@@ -10,5 +10,5 @@
 | teps              | §2.6.3 (TEPS, 64-root harmonic mean)           |
 
 ``python -m benchmarks.run`` executes reduced-size versions of all of them
-(scaling via ``--full``: it spawns multi-device subprocesses).
+(scaling via ``--full``: its grids run on sub-meshes of the process's devices).
 """

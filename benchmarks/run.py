@@ -108,6 +108,15 @@ def main() -> None:
         help="where to write the BENCH_comm.json trajectory artifact",
     )
     args = ap.parse_args()
+    if args.full and "scaling" not in args.skip:
+        from benchmarks import scaling
+
+        # the scaling grids run on sub-meshes of one process's devices; on
+        # the CPU that needs forced host devices before JAX starts
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={scaling.DEVICES}",
+        )
 
     from benchmarks import bfs_comm, breakdown, codecs, frontier_stats, teps
 
@@ -148,8 +157,6 @@ def main() -> None:
         ("teps (§2.6.3)", teps_suite),
     ]
     if args.full and "scaling" not in args.skip:
-        from benchmarks import scaling
-
         suites.append(("scaling (Fig 7.1/7.2)", scaling.main))
 
     failures = []

@@ -1,58 +1,41 @@
 """Paper Fig 7.1/7.2: strong and weak scaling of the distributed BFS.
 
-Real multi-rank executions on forced host devices (subprocess per grid
-size so each gets its own device count), comparing Baseline (raw) vs
+Real multi-rank executions in one process, each grid on a sub-mesh of
+``jax.devices()`` (chips on a TPU host; on the CPU, host devices forced
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before JAX
+starts, as ``benchmarks.run --full`` does), comparing Baseline (raw) vs
 compressed ('auto') — the paper's three-scenario scaling study at reduced
 scale.  Reports time per BFS and TEPS.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
-_WORKER = r"""
-import os, sys, time, json
 import numpy as np
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={int(sys.argv[1])*int(sys.argv[2])}"
-import jax, jax.numpy as jnp
-from repro.core import csr as csrmod, distributed_bfs as dbfs, validate
-from repro.graphgen import builder, kronecker
 
-rows, cols, scale, mode = int(sys.argv[1]), int(sys.argv[2]), int(sys.argv[3]), sys.argv[4]
-g = builder.build_csr(kronecker.kronecker_edges(scale, seed=3), n=1 << scale)
-mesh = jax.make_mesh((rows, cols), ("data", "model"))
-bg = csrmod.partition_2d(g, rows=rows, cols=cols)
-cfg = dbfs.DistBFSConfig(mode=mode)
-fn = dbfs.build_bfs(mesh, bg, cfg)
-src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-root = int(np.argmax(g.degrees()))
-parent, level, depth = fn(src_l, dst_l, jnp.int32(root))  # compile+run
-jax.block_until_ready(parent)
-t0 = time.perf_counter()
-reps = 3
-for _ in range(reps):
-    parent, level, depth = fn(src_l, dst_l, jnp.int32(root))
-    jax.block_until_ready(parent)
-dt = (time.perf_counter() - t0) / reps
-te = validate.traversed_edges(g, np.asarray(parent)[: g.n])
-print(json.dumps({"rows": rows, "cols": cols, "scale": scale, "mode": mode,
-                  "time_s": dt, "teps": te / dt, "depth": int(depth)}))
-"""
+#: host devices the largest grid needs when run on the CPU
+DEVICES = 8
 
 
-def _run_worker(rows: int, cols: int, scale: int, mode: str) -> dict:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _WORKER, str(rows), str(cols), str(scale), mode],
-        env=env, capture_output=True, text=True, timeout=1200,
-    )
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+def _run_grid(rows: int, cols: int, scale: int, mode: str) -> dict:
+    from repro.core import distributed_bfs as dbfs
+    from repro.core import validate
+    from repro.launch import graph500, runtime
+
+    mesh = runtime.grid_mesh(rows, cols)
+    graph = graph500.build_graph(scale, 16, rows, cols, seed=3)
+    search = graph500.Search(mesh, graph.bg, dbfs.DistBFSConfig(mode=mode))
+    root = int(np.argmax(graph.g.degrees()))
+    search(root)  # compile + run
+    reps = 3
+    times = []
+    for _ in range(reps):
+        parent, _, depth, dt = search(root)
+        times.append(dt)
+    search.release()
+    dt = sum(times) / reps
+    te = validate.traversed_edges(graph.g, parent[0])
+    return {"rows": rows, "cols": cols, "scale": scale, "mode": mode,
+            "time_s": dt, "teps": te / dt, "depth": depth}
 
 
 def run(strong_scale: int = 13, weak_base_scale: int = 11) -> list[dict]:
@@ -60,19 +43,22 @@ def run(strong_scale: int = 13, weak_base_scale: int = 11) -> list[dict]:
     # strong scaling: fixed problem, growing grid
     for r, c in ((1, 1), (2, 2), (2, 4)):
         for mode in ("raw", "auto"):
-            rec = _run_worker(r, c, strong_scale, mode)
+            rec = _run_grid(r, c, strong_scale, mode)
             rec["study"] = "strong"
             rows.append(rec)
     # weak scaling: problem grows with the grid (scale+2 per 4x ranks)
     for (r, c), sc in (((1, 1), weak_base_scale), ((2, 2), weak_base_scale + 2)):
         for mode in ("raw", "auto"):
-            rec = _run_worker(r, c, sc, mode)
+            rec = _run_grid(r, c, sc, mode)
             rec["study"] = "weak"
             rows.append(rec)
     return rows
 
 
 def main() -> None:
+    import jax
+
+    print(f"# device: {len(jax.devices())}x {jax.devices()[0].device_kind}")
     print("study,grid,scale,mode,time_s,TEPS,depth")
     for r in run():
         print(f"{r['study']},{r['rows']}x{r['cols']},{r['scale']},{r['mode']},"
@@ -80,4 +66,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    import os
+
+    os.environ.setdefault(
+        "XLA_FLAGS", f"--xla_force_host_platform_device_count={DEVICES}"
+    )
     main()

@@ -1,6 +1,7 @@
 """Distributed 2D-partitioned BFS with compressed collectives (paper Alg. 4).
 
-Runs on forced host devices so the full column/row collective pipeline
+Runs on a grid of ``jax.devices()`` (chips on a TPU host, forced host
+devices on the CPU) so the full column/row collective pipeline
 (TransposeVector ppermute -> compressed all-gather -> SpMV -> compressed
 all-to-all) executes for real, and compares the four wire plans.
 
@@ -37,6 +38,8 @@ ap.add_argument("--expand", default="coo",
                      "histogram-chosen split)")
 args = ap.parse_args()
 ROWS, COLS = (int(x) for x in args.grid.split("x"))
+# only the CPU platform's device count: on a TPU the grid is built from
+# the chips themselves
 os.environ.setdefault(
     "XLA_FLAGS", f"--xla_force_host_platform_device_count={ROWS * COLS}"
 )
@@ -52,11 +55,13 @@ from repro.core import expand as expand_mod  # noqa: E402
 from repro.core import validate  # noqa: E402
 from repro.core.centrality import tree_betweenness  # noqa: E402
 from repro.graphgen import builder, kronecker  # noqa: E402
+from repro.launch import runtime  # noqa: E402
 
 
 def main() -> None:
+    runtime.enable_compile_cache()
+    mesh = runtime.grid_mesh(ROWS, COLS)
     g = builder.build_csr(kronecker.kronecker_edges(args.scale, seed=3), n=1 << args.scale)
-    mesh = jax.make_mesh((ROWS, COLS), ("data", "model"))
     bg = csrmod.partition_2d(g, rows=ROWS, cols=COLS)
     deg = g.degrees()
     # same hub-root convention as the benchmark's acceptance rows

@@ -2,8 +2,9 @@
 
 Generation (untimed) -> Kernel 1: CSR construction + 2D partition (timed)
 -> Kernel 2: 64 BFS searches from the spec's valid-root sample, traversed
-in batches of B sources through the distributed 2D driver on forced host
-devices (every column/row collective executes for real) -> per-tree
+in batches of B sources through the distributed 2D driver on a grid of
+``jax.devices()`` (chips on a TPU host, forced host devices on the CPU;
+every column/row collective executes for real) -> per-tree
 Graph500 validation -> harmonic-mean TEPS via :mod:`benchmarks.teps`.
 The codec comparison of earlier revisions lives on in the frontier-bytes
 report: per-level frontier ids are priced raw vs compressed.
@@ -41,35 +42,32 @@ ap.add_argument("--no-validate", action="store_true",
                 help="skip the per-tree Graph500 5-rule validation")
 args = ap.parse_args()
 ROWS, COLS = (int(x) for x in args.grid.split("x"))
+# only the CPU platform's device count: on a TPU the grid is built from
+# the chips themselves
 os.environ.setdefault(
     "XLA_FLAGS", f"--xla_force_host_platform_device_count={ROWS * COLS}"
 )
 
-import time  # noqa: E402
-
-import numpy as np  # noqa: E402
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 from benchmarks import teps  # noqa: E402
 from repro.comm import registry  # noqa: E402
-from repro.core import csr as csrmod  # noqa: E402
 from repro.core import distributed_bfs as dbfs  # noqa: E402
 from repro.core import validate  # noqa: E402
-from repro.graphgen import builder, kronecker  # noqa: E402
+from repro.launch import graph500, runtime  # noqa: E402
 
 
 def main() -> None:
+    runtime.enable_compile_cache()
     print(f"# Graph500 scale={args.scale} edgefactor={args.edgefactor} "
           f"grid={ROWS}x{COLS} batch={args.batch} mode={args.mode} "
           f"policy={args.policy} expand={args.expand}")
-    edges = kronecker.kronecker_edges(args.scale, args.edgefactor, seed=1)
-
-    t0 = time.perf_counter()
-    g = builder.build_csr(edges, n=1 << args.scale)
-    bg = csrmod.partition_2d(g, rows=ROWS, cols=COLS)
+    mesh = runtime.grid_mesh(ROWS, COLS)
+    graph = graph500.build_graph(args.scale, args.edgefactor, ROWS, COLS)
+    g, bg = graph.g, graph.bg
     print(f"Kernel1 (construction + 2D partition): "
-          f"{time.perf_counter() - t0:.3f}s  m={g.m:,}  "
+          f"{graph.csr_s + graph.partition_s:.3f}s  m={g.m:,}  "
           f"chunk s={bg.part.chunk:,}  e_cap={bg.e_cap:,}")
 
     if args.roots % args.batch:
@@ -77,47 +75,38 @@ def main() -> None:
                          f"--batch {args.batch}")
     roots = teps.valid_roots(g, args.roots, seed=2)
 
-    mesh = jax.make_mesh((ROWS, COLS), ("data", "model"))
     cfg = dbfs.DistBFSConfig(mode=args.mode, policy=args.policy,
                              expand=args.expand)
-    fn = dbfs.build_bfs(mesh, bg, cfg)
-    blocks = dbfs.shard_blocked(mesh, bg, cfg)
+    search = graph500.Search(mesh, bg, cfg)
     codec = registry.make_codec(args.codec)  # factory call OUTSIDE Kernel 2
 
     # warm-up compile (untimed, like the spec's untimed setup)
-    warm = roots[: args.batch]
-    jax.block_until_ready(fn(*blocks, jnp.asarray(warm))[0])
+    search(roots[: args.batch])
 
     teps_list, comm_raw, comm_comp = [], 0, 0
     for lo in range(0, args.roots, args.batch):
         chunk = roots[lo : lo + args.batch]
-        t0 = time.perf_counter()
-        parent, level, depth = fn(*blocks, jnp.asarray(chunk))
-        jax.block_until_ready(parent)
-        dt = time.perf_counter() - t0
-        parent_np = np.asarray(parent)[:, : g.n]
-        level_np = np.asarray(level)[:, : g.n]
+        parent_np, level_np, depth, dt = search(chunk)
         per_source = dt / args.batch
-        for k, root in enumerate(chunk):
+        if not args.no_validate:
+            graph500.check_trees(g, chunk, parent_np, level_np)
+        for k in range(len(chunk)):
             te = validate.traversed_edges(g, parent_np[k])
-            if not args.no_validate:
-                v = validate.validate_bfs_tree(g, parent_np[k], int(root),
-                                               level_np[k])
-                assert v.ok, (int(root), v.failures)
             teps_list.append(te / per_source)
             lv = level_np[k]
-            for d in range(1, int(depth) + 1):
+            for d in range(1, depth + 1):
                 ids = np.nonzero(lv == d)[0].astype(np.uint32)
                 if ids.size:
                     comm_raw += ids.size * 4
                     comm_comp += len(codec.encode(ids))
         print(f"  roots[{lo}:{lo + args.batch}]: {dt:.3f}s "
-              f"({per_source:.3f}s/source)  depth={int(depth)}  "
+              f"({per_source:.3f}s/source)  depth={depth}  "
               f"min TEPS {min(teps_list[lo:]):.3e}")
 
     hm = teps.harmonic_mean(teps_list)
     print(f"\nTEPS harmonic mean over {args.roots} roots "
-          f"(batch {args.batch}): {hm:.3e}")
+          f"(batch {args.batch}, {len(jax.devices())}x "
+          f"{jax.devices()[0].device_kind}): {hm:.3e}")
     print(f"frontier bytes: raw={comm_raw:,} {args.codec}={comm_comp:,} "
           f"({100 * (1 - comm_comp / max(comm_raw, 1)):.1f}% reduction — "
           f"paper: >90%)")

@@ -36,8 +36,12 @@ from repro.comm.formats import (
 from repro.comm.ladder import BucketLadder, stream_stats
 from repro.comm.stats import CommStats
 from repro.kernels.bitpack import ops as bp
-from repro.kernels.bitpack import ref as bpref
 from repro.kernels.quant import ref as quant
+
+
+def _compact_planes(bits: jax.Array, cap: int, s: int) -> jax.Array:
+    """(P, s) membership planes -> (P, cap) sorted ids, padded with ``s``."""
+    return jax.vmap(lambda x: bp.compact_ids(x, cap, fill=s)[0])(bits)
 
 
 def _scatter_membership(u_ids: jax.Array, s: int, group_size: int) -> jax.Array:
@@ -89,10 +93,11 @@ def allgather_membership(
     ex = AdaptiveExchange(phase, axis, group_size, ladder, stats)
     if not ladder.specs:  # degenerate ladder: dense bitmap only
         return ex.dispatch(None, [lambda _: gather_bitmap(ex, bits)])
-    ids, count, exc_count = stream_stats(bits, s)
+    count, exc_count = stream_stats(bits)
 
     def sparse_branch(fmt: IdStreamFormat):
         def run(_):
+            ids, _ = bp.compact_ids(bits, fmt.spec.cap, fill=s)
             words, meta = fmt.pack(ids, count)
             g_words = ex.all_gather(words, fmt=fmt.name).reshape(
                 group_size, fmt.data_words
@@ -168,11 +173,12 @@ def allgather_membership_planes(
     ex = AdaptiveExchange(phase, axis, group_size, ladder, stats, planes=b)
     if not ladder.specs:
         return ex.dispatch(None, [lambda _: gather_bitmap_planes(ex, bits)])
-    ids, counts, exc_counts = jax.vmap(lambda x: stream_stats(x, s))(bits)
+    counts, exc_counts = stream_stats(bits)
     my_bucket = jnp.max(jax.vmap(ladder.bucket_for)(counts, exc_counts))
 
     def sparse_branch(fmt: IdStreamFormat):
         def run(_):
+            ids = _compact_planes(bits, fmt.spec.cap, s)
             words, meta = jax.vmap(fmt.pack)(ids, counts)  # (B, dw), (B, 2)
             pmeta = pack_plane_meta(meta[:, 0], meta[:, 1])  # (B,)
             g_words = ex.all_gather(words, fmt=fmt.name).reshape(
@@ -272,9 +278,7 @@ def alltoall_min_candidates(
     )
 
     bits = prop < INF
-    ids, counts = jax.vmap(lambda b: bp.compact_ids(b, s, fill=s))(bits)
-    gaps = jax.vmap(bpref.gaps_from_sorted)(ids, counts)
-    exc_counts = jnp.sum((gaps >> 16) > 0, axis=1)
+    counts, exc_counts = stream_stats(bits)
     my_bucket = jnp.max(jax.vmap(ladder.bucket_for)(counts, exc_counts))
     base = 0 if n_c is None else jax.lax.axis_index(axis) * n_c
 
@@ -282,6 +286,8 @@ def alltoall_min_candidates(
         cap = fmt.spec.cap
 
         def run(_):
+            ids = _compact_planes(bits, cap, s)
+
             def pack_one(ids_d, count_d, prop_d):
                 par = prop_d[jnp.clip(ids_d[:cap], 0, s - 1)] - base
                 return fmt.pack(ids_d, count_d, payload=par)
@@ -376,9 +382,7 @@ def alltoall_min_candidates_planes(
     prop_t = jnp.moveaxis(prop, 0, 1)  # (c, B, s): all-to-all split layout
     bits = prop_t < INF
     flat = bits.reshape(c * b, s)
-    ids, counts = jax.vmap(lambda x: bp.compact_ids(x, s, fill=s))(flat)
-    gaps = jax.vmap(bpref.gaps_from_sorted)(ids, counts)
-    exc_counts = jnp.sum((gaps >> 16) > 0, axis=1)
+    counts, exc_counts = stream_stats(flat)
     my_bucket = jnp.max(jax.vmap(ladder.bucket_for)(counts, exc_counts))
     base = 0 if n_c is None else jax.lax.axis_index(axis) * n_c
 
@@ -386,6 +390,8 @@ def alltoall_min_candidates_planes(
         cap = fmt.spec.cap
 
         def run(_):
+            ids = _compact_planes(flat, cap, s)
+
             def pack_one(ids_d, count_d, prop_d):
                 par = prop_d[jnp.clip(ids_d[:cap], 0, s - 1)] - base
                 return fmt.pack(ids_d, count_d, payload=par)
@@ -516,9 +522,7 @@ def ppermute_min_block(
     nb, b, s = block.shape
     flat = block.reshape(nb * b, s)
     bits = flat < INF
-    ids, counts = jax.vmap(lambda x: bp.compact_ids(x, s, fill=s))(bits)
-    gaps = jax.vmap(bpref.gaps_from_sorted)(ids, counts)
-    exc_counts = jnp.sum((gaps >> 16) > 0, axis=1)
+    counts, exc_counts = stream_stats(bits)
     if ladder.specs:
         my_bucket = jnp.max(jax.vmap(ladder.bucket_for)(counts, exc_counts))
         my_bucket = jnp.where(gate, my_bucket, 0)
@@ -529,6 +533,8 @@ def ppermute_min_block(
         cap = fmt.spec.cap
 
         def run(_):
+            ids = _compact_planes(bits, cap, s)
+
             def pack_one(ids_d, count_d, block_d):
                 par = block_d[jnp.clip(ids_d[:cap], 0, s - 1)]
                 return fmt.pack(ids_d, count_d, payload=par)
@@ -588,9 +594,7 @@ def ppermute_membership_block(
     """
     nb, b, s = block.shape
     flat = block.reshape(nb * b, s)
-    ids, counts = jax.vmap(lambda x: bp.compact_ids(x, s, fill=s))(flat)
-    gaps = jax.vmap(bpref.gaps_from_sorted)(ids, counts)
-    exc_counts = jnp.sum((gaps >> 16) > 0, axis=1)
+    counts, exc_counts = stream_stats(flat)
     if ladder.specs:
         my_bucket = jnp.max(jax.vmap(ladder.bucket_for)(counts, exc_counts))
         my_bucket = jnp.where(gate, my_bucket, 0)
@@ -601,6 +605,7 @@ def ppermute_membership_block(
         cap = fmt.spec.cap
 
         def run(_):
+            ids = _compact_planes(flat, cap, s)
             words, meta = jax.vmap(fmt.pack)(ids, counts)
             if b > 1:
                 meta = pack_plane_meta(meta[:, 0], meta[:, 1]).reshape(nb, b)

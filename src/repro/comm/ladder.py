@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from repro.comm.formats import IdStreamFormat, IdStreamSpec
 from repro.comm.threshold import ThresholdPolicy
-from repro.kernels.bitpack import ops as bp
 from repro.kernels.bitpack import ref as bpref
 
 
@@ -101,9 +100,18 @@ class BucketLadder:
         return tuple(IdStreamFormat(spec, self.payload_width) for spec in self.specs)
 
 
-def stream_stats(bits: jax.Array, s: int):
-    """ids (s,), count, exception count of the gap stream (for bucketing)."""
-    ids, count = bp.compact_ids(bits, s, fill=s)
-    gaps = bpref.gaps_from_sorted(ids, count)
-    exc_count = jnp.sum((gaps >> 16) > 0)
-    return ids, count, exc_count
+def stream_stats(bits: jax.Array):
+    """(count, exception count) of a membership vector's gap stream.
+
+    A set bit's gap is its distance to the previous set bit (to 0 for the
+    first), and an exception is a gap that overflows the 16 low bits —
+    what bucket selection needs, found with running maxima instead of
+    compacting the stream; sparse branches compact only their capacity."""
+    idx = jnp.arange(bits.shape[-1], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(bits, idx, 0), axis=bits.ndim - 1)
+    prev = jnp.concatenate(
+        [jnp.zeros(bits.shape[:-1] + (1,), jnp.int32), last[..., :-1]], axis=-1
+    )
+    count = jnp.sum(bits, axis=-1, dtype=jnp.int32)
+    exc_count = jnp.sum(bits & (idx - prev >= 1 << 16), axis=-1, dtype=jnp.int32)
+    return count, exc_count

@@ -1,10 +1,10 @@
 """Single-device level-synchronous BFS (paper Algorithm 2, one processor).
 
-Edge-centric SpMV formulation: one BFS level is a masked ``segment_min`` of
+Edge-centric SpMV formulation: one BFS level is a per-destination minimum of
 candidate parents over the symmetric COO edge list — the linear-algebra view
 the paper itself uses (``t = A (x) f`` over a min-parent semiring), with the
-GPU warp-queue mechanics replaced by fully-vectorizable segment reductions
-(DESIGN.md §3, hardware adaptation).
+GPU warp-queue mechanics replaced by fully-vectorizable scans over the
+destination-major edge list (DESIGN.md §3, hardware adaptation).
 
 Direction optimization (Beamer, paper §3.1) is a *policy*, resolved through
 :mod:`repro.core.traversal`: ``top_down`` pushes from the frontier,
@@ -179,6 +179,7 @@ def _bfs_batched(src, dst, roots, n, policy, max_levels, expand, extra,
     pol = traversal.resolve(policy)
     alg = algebra_mod.resolve(algebra)
     backend = expand_mod.resolve(expand)
+    src, dst = expand_mod.dst_major(src, dst)
     block = backend.local_block(src, dst, extra, n, n)
     oracle = traversal.DensityOracle(n)
     # the degree vector is computed once before the level loop, and only
@@ -186,7 +187,7 @@ def _bfs_batched(src, dst, roots, n, policy, max_levels, expand, extra,
     # plus-times algebra's x = v/deg source messages
     deg = None
     if (pol.uses_top_down and pol.uses_bottom_up) or alg.needs_deg:
-        deg = traversal.degree_vector(src, dst, n, n)
+        deg = traversal.degree_vector(dst, n)
     out = jax.lax.while_loop(
         lambda s: s.active & (s.depth < max_levels),
         lambda s: traversal.level_once(src, dst, n, pol, oracle, s, deg=deg,
@@ -251,11 +252,12 @@ def _bfs_levels_batched(src, dst, roots, n, max_levels, policy, expand, extra):
     pol = traversal.resolve(policy)
     alg = algebra_mod.resolve("bfs")
     backend = expand_mod.resolve(expand)
+    src, dst = expand_mod.dst_major(src, dst)
     block = backend.local_block(src, dst, extra, n, n)
     oracle = traversal.DensityOracle(n)
     deg = None
     if pol.uses_top_down and pol.uses_bottom_up:
-        deg = traversal.degree_vector(src, dst, n, n)
+        deg = traversal.degree_vector(dst, n)
 
     def body(state, _):
         state = jax.lax.cond(

@@ -80,7 +80,9 @@ class BlockedGraph:
     """2D-blocked edge arrays, shaped (R, C, e_cap) with local indices.
 
     ``src_local`` indexes into the column slice [0, n_c); ``dst_local`` into
-    the row slice [0, n_r).  Padding edges use (n_c, n_r) sentinels.
+    the row slice [0, n_r).  Each block is destination-major (sorted by
+    destination, then source), and padding edges at the end use (n_c, n_r)
+    sentinels.
     """
 
     part: Partition2D
@@ -102,7 +104,7 @@ class ELLBlocks:
     (row-local destination) lists its column-local frontier-side neighbors,
     sentinel-padded with ``n_c`` (a vertex id that never hits a frontier
     bitmap).  Shapes are static across blocks — the slab width is the max
-    over blocks, rounded to the SpMV kernel's degree chunk — so the arrays
+    over blocks, rounded to a multiple of 8 — so the arrays
     shard alongside the COO edge arrays.
     """
 
@@ -160,7 +162,7 @@ def _block_degrees(src: np.ndarray, dst: np.ndarray, part: Partition2D) -> np.nd
 
 def ell_slab_width(bg: BlockedGraph, deg_multiple: int = 8) -> int:
     """The slab width :func:`ell_blocked` will use: the max row degree over
-    ALL blocks, rounded to the SpMV degree chunk.  The single place the
+    ALL blocks, rounded to a multiple of 8.  The single place the
     pure-ELL affordability estimate lives, so memory guards (the benchmark's
     slab budget) cannot drift from what the container actually allocates."""
     part = bg.part
@@ -259,14 +261,18 @@ def partition_2d(
     """
     n, _ = padded_geometry(g.n, rows, cols, chunk_multiple)
     part = Partition2D(n=n, n_orig=g.n, rows=rows, cols=cols)
-    src, dst = g.src.astype(np.int64), g.dst.astype(np.int64)
+    # the symmetric CSR read transposed lists its edges destination-major
+    # (by destination, then source): the layout the COO expansion reads.
+    # The stable sort by block keeps that order inside every block.
+    src, dst = g.dst, g.src
 
     bj = src // part.n_c  # block column of each edge
     bi = dst // part.n_r  # block row
-    block = bi * cols + bj
-    order = np.argsort(block, kind="stable")
-    src, dst, block = src[order], dst[order], block[order]
+    block = (bi * cols + bj).astype(np.min_scalar_type(rows * cols))
     counts = np.bincount(block, minlength=rows * cols)
+    if rows * cols > 1:
+        order = np.argsort(block, kind="stable")
+        src, dst = src[order], dst[order]
     e_cap = _round_up(max(int(counts.max()), 1), e_cap_multiple)
 
     src_l = np.full((rows * cols, e_cap), part.n_c, dtype=np.int32)
@@ -277,8 +283,8 @@ def partition_2d(
         if cnt == 0:
             continue
         i, j = divmod(b, cols)
-        src_l[b, :cnt] = (src[s0 : s0 + cnt] - j * part.n_c).astype(np.int32)
-        dst_l[b, :cnt] = (dst[s0 : s0 + cnt] - i * part.n_r).astype(np.int32)
+        np.subtract(src[s0 : s0 + cnt], j * part.n_c, out=src_l[b, :cnt])
+        np.subtract(dst[s0 : s0 + cnt], i * part.n_r, out=dst_l[b, :cnt])
 
     return BlockedGraph(
         part=part,

@@ -15,8 +15,8 @@ chunk q = i*C + j of width s):
      t_i = A_ij (x) f_j) or *pull* (bottom-up: only unreached destinations
      accumulate, gated on an unreached-bitmap all-gather over the grid
      row) — dispatched through the *expansion backend* (``cfg.expand``):
-     ``coo`` (flat segment_min over the padded edge arrays), ``ell``
-     (dense neighbor slabs through the Pallas SpMV kernels), or ``hybrid``
+     ``coo`` (first-hit scan over the padded edge arrays), ``ell``
+     (dense neighbor slabs through the ELL SpMV gather), or ``hybrid``
      (per-block degree split; hubs stay COO).  Expansion is compute-local:
      backend choice changes no collective and no CommStats entry.
   4. **row phase**: top-down exchanges per-destination candidate subchunks
@@ -53,7 +53,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.comm import AdaptiveExchange, CommStats, ThresholdPolicy
 from repro.comm import collectives as comm_cc
 from repro.comm import registry as wire_registry
@@ -200,7 +199,7 @@ def _bfs_local(
         # actual consumption: a recorded-but-dead psum would be DCE'd from
         # the HLO and break the ledger reconciliation.
         ex_degree = AdaptiveExchange(f"{p}/degree", cfg.col_axis, c, None, stats)
-        deg_slice = traversal.degree_vector(src_l, dst_l, n_c, n_r)
+        deg_slice = traversal.degree_vector(dst_l, n_r)
         deg_row = ex_degree.psum(deg_slice, fmt="degree")
         deg_own = jax.lax.dynamic_slice(deg_row, (j * s,), (s,))
 
@@ -345,16 +344,20 @@ def build_bfs(
     local = functools.partial(
         _bfs_local, part=part, cfg=cfg, stats=stats, threshold=threshold
     )
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(blk_spec, blk_spec, extra_specs, P()),
         out_specs=(out_spec, out_spec, P()),
+        # the direction/plane-mask conds mix replicated consensus values with
+        # per-rank planes; like the other shard_map callers, skip the
+        # varying-axis type check
+        check_vma=False,
     )
     jitted = jax.jit(mapped)
     n_blocks = 2 + len(backend.extra_ndims)
 
-    def run(*args):
+    def jit_args(args):
         if len(args) != n_blocks + 1:
             raise TypeError(
                 f"expansion backend {backend.name!r} expects "
@@ -363,14 +366,19 @@ def build_bfs(
             )
         *blocks, root = args
         roots = bfs.validate_roots(root, part.n_orig)
-        squeeze = roots.ndim == 0
-        parent, level, depth = jitted(
-            blocks[0], blocks[1], tuple(blocks[2:]), jnp.atleast_1d(roots)
-        )
+        return (blocks[0], blocks[1], tuple(blocks[2:]),
+                jnp.atleast_1d(roots)), roots.ndim == 0
+
+    def run(*args):
+        jargs, squeeze = jit_args(args)
+        parent, level, depth = jitted(*jargs)
         if squeeze:
             return parent[0], level[0], depth
         return parent, level, depth
 
+    # ahead-of-time lowering for the same arguments; a ``.compile()`` of it
+    # is the executable later calls with those shapes reuse
+    run.lower_call = lambda *args: jitted.lower(*jit_args(args)[0])
     return run
 
 

@@ -6,13 +6,14 @@ the third leg of a distributed BFS level — the **local expansion** that
 turns the gathered frontier slice into per-destination candidate parents.
 Data-structure choice dominates on-node BFS cost once communication is
 optimized (Buluc & Madduri, arXiv:1104.4518), and the winning structure is
-degree-dependent: dense ELL neighbor slabs stream through the Pallas SpMV
-kernels, but hub rows make a slab-wide ELL unaffordable, so hubs want to
+degree-dependent: dense ELL neighbor slabs stream through the SpMV
+gather, but hub rows make a slab-wide ELL unaffordable, so hubs want to
 stay COO (Bisson et al., arXiv:1408.1605).  Three backends, resolved by
 name through :func:`repro.comm.registry.expansion`:
 
-* ``coo``    — the flat segment_min over the sentinel-padded edge arrays
-  (the historical path, extracted here).
+* ``coo``    — the sentinel-padded, destination-major edge arrays; each
+  row's minimum frontier source is its first hit, found by one running
+  minimum over the edges (no scatter).
 * ``ell``    — dense ``(rows, k)`` neighbor blocks driven through
   :mod:`repro.kernels.spmv` push/pull (``k`` covers the heaviest row).
 * ``hybrid`` — per-block degree split: rows with degree <= ``k`` live in
@@ -47,7 +48,6 @@ from repro.comm.formats import INF
 from repro.core import csr as csrmod
 from repro.graphgen import builder
 from repro.kernels.bitpack import ops as bp_ops
-from repro.kernels.spmv import ops as spmv_ops
 from repro.kernels.spmv import ref as spmv_ref
 
 #: name aliases accepted by :func:`resolve` (the example's ``--expand auto``)
@@ -83,7 +83,10 @@ class LocalBlock(NamedTuple):
     backend, the hub residue for ``hybrid``, unused (but carried for the
     degree vector) for ``ell``; ``nbr`` is the dense ELL slab or ``None``.
     Sentinels follow the partition convention: ``n_cols`` on the source
-    side, ``n_rows`` on the destination side.
+    side, ``n_rows`` on the destination side.  COO edges are
+    destination-major (sorted by destination, then source; the partition
+    and the single-device driver lay them out so), and ``row_ptr`` holds
+    each destination row's first edge.
     """
 
     src: jax.Array  # (e,) column-local sources
@@ -91,44 +94,89 @@ class LocalBlock(NamedTuple):
     nbr: jax.Array | None  # (n_rows, k) ELL slab, sentinel n_cols
     n_rows: int
     n_cols: int
+    row_ptr: jax.Array | None = None  # (n_rows + 1,) COO row offsets
 
 
-def _coo_push(src, dst, n_rows: int, n_cols: int, f):
+def dst_major(src, dst):
+    """Sort an edge list destination-major (by destination, then source),
+    the layout the COO expansion reads."""
+    dst, src = jax.lax.sort((dst, src), num_keys=2)
+    return src, dst
+
+
+#: positions are int32: a block past 2**31 - 1 edge slots still lowers (the
+#: dry-run cells), with positions clamped, but cannot run
+_POS_MAX = int(jnp.iinfo(jnp.int32).max)
+
+
+def row_offsets(dst, n_rows: int) -> jax.Array:
+    """(n_rows + 1,) start of each destination row in a destination-major
+    edge array (sentinel destinations ``>= n_rows`` sort past the last):
+    a binary search per row, all rows at once."""
+    e = min(dst.shape[0], _POS_MAX)
+    rows = jax.lax.iota(dst.dtype, n_rows + 1)
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = lo + (hi - lo) // 2
+        right = (lo < hi) & (jnp.take(dst, mid, mode="clip") < rows)
+        return (jnp.where(right, mid + 1, lo),
+                jnp.where(right | (lo >= hi), hi, mid))
+
+    lo = jnp.zeros((n_rows + 1,), jnp.int32)
+    hi = jnp.full((n_rows + 1,), e, jnp.int32)
+    lo, _ = jax.lax.fori_loop(0, e.bit_length(), halve, (lo, hi))
+    return lo
+
+
+def _first_hit(src, row_ptr, hit):
+    """Per destination row, the smallest source whose edge ``hit``s.
+
+    Edges are destination-major with ascending sources inside each row, so
+    the row's minimum hit is its FIRST hit: one reverse running minimum of
+    hit positions finds it for every row at once — a streaming pass over
+    the edges instead of a scatter into the rows."""
+    e = min(src.shape[0], _POS_MAX)
+    pos = jnp.where(hit, jax.lax.iota(jnp.int32, src.shape[0]), e)
+    nxt = jax.lax.cummin(pos, reverse=True)  # first hit at or after slot
+    first = jnp.take(nxt, row_ptr[:-1], mode="clip")
+    found = (row_ptr[:-1] < row_ptr[1:]) & (first < row_ptr[1:])
+    return jnp.where(found, jnp.take(src, first, mode="clip"), INF)
+
+
+def _coo_push(src, row_ptr, n_cols: int, f):
     """(B, n_cols) frontier planes -> (B, n_rows) min frontier source per
-    destination (column-LOCAL ids) via masked segment_min over the edges."""
+    destination (column-LOCAL ids) over destination-major edges."""
 
     def one(fp):
-        act = fp[jnp.clip(src, 0, n_cols - 1)] & (src < n_cols)
-        cand = jnp.where(act, src, INF)
-        return jax.ops.segment_min(cand, dst, num_segments=n_rows + 1)[:n_rows]
+        hit = fp[jnp.clip(src, 0, n_cols - 1)] & (src < n_cols)
+        return _first_hit(src, row_ptr, hit)
 
-    return jax.vmap(one)(f)
+    return jax.lax.map(one, f)
 
 
-def _coo_pull(src, dst, n_rows: int, n_cols: int, f, unreached):
+def _coo_pull(src, row_ptr, n_cols: int, f, unreached):
     """Pull over COO edges: the frontier is probed through its *packed*
     bitmap (the representation switch the pull direction is about), and
-    only unreached destinations accumulate candidates."""
+    only unreached destinations take a candidate."""
     n_cp = _chunk_pad(n_cols)
     words = _pack_planes(f)
 
     def one(wp, un):
         hit = spmv_ref.frontier_bit(wp, src, n_cp) & (src < n_cols)
-        pull = un[jnp.clip(dst, 0, n_rows - 1)] & (dst < n_rows)
-        cand = jnp.where(hit & pull, src, INF)
-        return jax.ops.segment_min(cand, dst, num_segments=n_rows + 1)[:n_rows]
+        return jnp.where(un, _first_hit(src, row_ptr, hit), INF)
 
-    return jax.vmap(one)(words, unreached)
+    return jax.lax.map(lambda a: one(*a), (words, unreached))
 
 
 def _ell_push(nbr, n_cols: int, f):
-    """ELL slab push through the plane-batched Pallas SpMV dispatch."""
-    return spmv_ops.spmv_min_planes(nbr, _pack_planes(f), _chunk_pad(n_cols))
+    """ELL slab push through the plane-batched SpMV."""
+    return spmv_ref.spmv_min_planes(nbr, _pack_planes(f), _chunk_pad(n_cols))
 
 
 def _ell_pull(nbr, n_cols: int, f, unreached):
     """ELL pull: resident frontier + unreached bitmaps, finished rows INF."""
-    return spmv_ops.spmv_pull_min_planes(
+    return spmv_ref.spmv_pull_min_planes(
         nbr, _pack_planes(f), _pack_planes(unreached), _chunk_pad(n_cols)
     )
 
@@ -145,7 +193,7 @@ def _coo_push_value(src, dst, n_rows, n_cols, f, x, alg, row_base, col_base):
         cand = jnp.where(act, msg, alg.empty)
         return alg.segment_combine(cand, dst, n_rows + 1)[:n_rows]
 
-    return jax.vmap(one)(f, x)
+    return jax.lax.map(lambda a: one(*a), (f, x))
 
 
 def _coo_pull_value(src, dst, n_rows, n_cols, f, unreached, x, alg,
@@ -164,12 +212,12 @@ def _coo_pull_value(src, dst, n_rows, n_cols, f, unreached, x, alg,
         cand = jnp.where(hit & pull, msg, alg.empty)
         return alg.segment_combine(cand, dst, n_rows + 1)[:n_rows]
 
-    return jax.vmap(one)(words, unreached, x)
+    return jax.lax.map(lambda a: one(*a), (words, unreached, x))
 
 
 def _ell_push_value(nbr, n_cols: int, f, x, alg, row_base, col_base):
-    """ELL value push through the op x reduce gspmm dispatch."""
-    return spmv_ops.gspmm_planes(
+    """ELL value push through the op x reduce gspmm."""
+    return spmv_ref.gspmm_planes(
         nbr, _pack_planes(f), x, _chunk_pad(n_cols), alg,
         row_base=row_base, col_base=col_base,
     )
@@ -177,7 +225,7 @@ def _ell_push_value(nbr, n_cols: int, f, x, alg, row_base, col_base):
 
 def _ell_pull_value(nbr, n_cols: int, f, unreached, x, alg, row_base, col_base):
     """ELL value pull: masked destinations collapse to the empty sentinel."""
-    return spmv_ops.gspmm_planes(
+    return spmv_ref.gspmm_planes(
         nbr, _pack_planes(f), x, _chunk_pad(n_cols), alg,
         row_base=row_base, col_base=col_base, u_words=_pack_planes(unreached),
     )
@@ -240,13 +288,14 @@ class CooExpansion(ExpansionBackend):
 
     def local_block(self, src, dst, extra, n_rows, n_cols):
         assert extra == (), extra
-        return LocalBlock(src=src, dst=dst, nbr=None, n_rows=n_rows, n_cols=n_cols)
+        return LocalBlock(src=src, dst=dst, nbr=None, n_rows=n_rows,
+                          n_cols=n_cols, row_ptr=row_offsets(dst, n_rows))
 
     def push_planes(self, blk, f):
-        return _coo_push(blk.src, blk.dst, blk.n_rows, blk.n_cols, f)
+        return _coo_push(blk.src, blk.row_ptr, blk.n_cols, f)
 
     def pull_planes(self, blk, f, unreached):
-        return _coo_pull(blk.src, blk.dst, blk.n_rows, blk.n_cols, f, unreached)
+        return _coo_pull(blk.src, blk.row_ptr, blk.n_cols, f, unreached)
 
     def push_value_planes(self, blk, f, x, alg, *, row_base=0, col_base=0):
         return _coo_push_value(
@@ -339,19 +388,20 @@ class HybridExpansion(ExpansionBackend):
     def local_block(self, src, dst, extra, n_rows, n_cols):
         nbr, res_src, res_dst = extra
         return LocalBlock(
-            src=res_src, dst=res_dst, nbr=nbr, n_rows=n_rows, n_cols=n_cols
+            src=res_src, dst=res_dst, nbr=nbr, n_rows=n_rows, n_cols=n_cols,
+            row_ptr=row_offsets(res_dst, n_rows),
         )
 
     def push_planes(self, blk, f):
         return jnp.minimum(
             _ell_push(blk.nbr, blk.n_cols, f),
-            _coo_push(blk.src, blk.dst, blk.n_rows, blk.n_cols, f),
+            _coo_push(blk.src, blk.row_ptr, blk.n_cols, f),
         )
 
     def pull_planes(self, blk, f, unreached):
         return jnp.minimum(
             _ell_pull(blk.nbr, blk.n_cols, f, unreached),
-            _coo_pull(blk.src, blk.dst, blk.n_rows, blk.n_cols, f, unreached),
+            _coo_pull(blk.src, blk.row_ptr, blk.n_cols, f, unreached),
         )
 
     def push_value_planes(self, blk, f, x, alg, *, row_base=0, col_base=0):

@@ -10,12 +10,12 @@ expansion.  This module makes that choice a pluggable policy, resolved by
 name through :func:`repro.comm.registry.traversal`:
 
 * ``top_down``      — push: frontier sources propose parents
-  (``segment_min`` over the edge list); the distributed row phase
+  (a minimum over each destination's edges); the distributed row phase
   exchanges candidate id streams (the ALLTOALLV analog).
 * ``bottom_up``     — pull: only unreached destinations accumulate
   candidates, the frontier is probed through its *packed bitmap* (the same
-  vertical width-1 gather the Pallas SpMV kernels use; the ELL hot-spot
-  version is :mod:`repro.kernels.spmv.pull`), and the distributed row
+  vertical width-1 gather the ELL expansion uses; the ELL slab version is
+  :func:`repro.kernels.spmv.ref.spmv_pull_min_planes`), and the distributed row
   phase swaps the id-stream ALLTOALLV for an unreached-bitmap all-gather
   plus a found-bitmap + bit-packed-parent exchange
   (:class:`repro.comm.BitmapParentFormat`).
@@ -41,19 +41,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.comm import registry as wire_registry
-from repro.comm.formats import INF, pack_bitmap
+from repro.comm.formats import INF
 from repro.comm.ladder import BucketLadder
 from repro.core import expand as expand_mod
 from repro.kernels.bitpack import ops as bp_ops
 from repro.kernels.popcount import ops as pc_ops
-
-
-def _pad_to_chunk(bits: jax.Array) -> jax.Array:
-    """Zero-pad a membership vector to the 1024-bit packing chunk."""
-    pad = (-bits.shape[0]) % 1024
-    if pad:
-        bits = jnp.concatenate([bits, jnp.zeros((pad,), bits.dtype)])
-    return bits
 
 
 def ladder_alpha(
@@ -78,12 +70,11 @@ def ladder_alpha(
 class DensityOracle:
     """Popcount-based frontier-density oracle (direction AND wire signal).
 
-    ``local_count`` is the membership popcount over the packed bitmap —
-    computed by the :mod:`repro.kernels.popcount` kernel, and the exact
-    quantity the BucketLadder thresholds on for the wire representation;
-    ``plane_counts`` is its multi-source form (one kernel call over all B
-    frontier planes).  ``next_direction`` applies alpha/beta hysteresis on
-    the same count, per plane, and accepts the *anticipatory* Beamer signal:
+    ``plane_counts`` is the membership popcount over the packed bitmaps —
+    computed by the :mod:`repro.kernels.popcount` kernel in one call over
+    all B frontier planes, and the exact quantity the BucketLadder
+    thresholds on for the wire representation.  ``next_direction``
+    applies alpha/beta hysteresis on the same count, per plane, and accepts the *anticipatory* Beamer signal:
     ``m_f`` (edges incident to the frontier) against ``m_u`` (edges incident
     to still-unreached vertices).  A hub entering the frontier blows up
     ``m_f`` one level before the vertex count crosses ``alpha * n``, so the
@@ -96,11 +87,6 @@ class DensityOracle:
     beta: float = 0.05  # fall back to top-down below this density
     alpha_mf: float = 14.0  # Beamer edge heuristic: enter pull when
     #                         alpha_mf * m_f > m_u (m_f from the degree dot)
-
-    def local_count(self, bits: jax.Array) -> jax.Array:
-        """Frontier size via the popcount kernel over the packed bitmap."""
-        words = pack_bitmap(_pad_to_chunk(bits))
-        return jnp.sum(pc_ops.popcount_blocks(words)).astype(jnp.int32)
 
     def plane_counts(self, bits: jax.Array) -> jax.Array:
         """Per-plane frontier sizes of ``(B, n)`` membership planes.
@@ -148,19 +134,16 @@ class DensityOracle:
         )
 
 
-def degree_vector(src, dst, n_src: int, n_dst: int) -> jax.Array:
-    """Per-destination degree of one edge block (padding excluded).
+def degree_vector(dst, n_dst: int) -> jax.Array:
+    """Per-destination degree of one destination-major edge block (padding
+    excluded): the differences of its row offsets.
 
-    The single masked segment-sum convention behind the anticipatory
-    oracle on BOTH drivers: ``bfs`` feeds the full symmetric edge list
-    (``n_src == n_dst == n``), the distributed driver its column-local
-    block (``n_c``/``n_r`` bounds, followed by a grid-row psum) — one
-    definition, so the two m_f signals cannot drift.
+    The single convention behind the anticipatory oracle on BOTH drivers:
+    ``bfs`` feeds the full symmetric edge list (``n_dst == n``), the
+    distributed driver its block (``n_r`` bound, followed by a grid-row
+    psum) — one definition, so the two m_f signals cannot drift.
     """
-    valid = (src < n_src) & (dst < n_dst)
-    return jax.ops.segment_sum(
-        valid.astype(jnp.int32), jnp.minimum(dst, n_dst), num_segments=n_dst + 1
-    )[:n_dst]
+    return jnp.diff(expand_mod.row_offsets(dst, n_dst))
 
 
 def edge_signals(deg, new, parent):

@@ -31,6 +31,17 @@ class ValidationResult:
         return self.ok
 
 
+def _ranges(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """Concatenated index ranges ``[lo_k, hi_k)`` — a vectorized ragged
+    gather, so frontier expansions cost no Python loop per vertex."""
+    lens = hi - lo
+    total = int(lens.sum())
+    if total == 0:
+        return np.zeros(0, np.int64)
+    ends = np.cumsum(lens)
+    return np.arange(total, dtype=np.int64) + np.repeat(lo - (ends - lens), lens)
+
+
 def compute_levels(parent: np.ndarray, root: int, max_iter: int | None = None) -> np.ndarray:
     """Levels by pointer-jumping over parent links; -2 marks a cycle/overflow."""
     n = parent.shape[0]
@@ -47,11 +58,27 @@ def compute_levels(parent: np.ndarray, root: int, max_iter: int | None = None) -
         depth += 1
         lo = np.searchsorted(parents_sorted, frontier, side="left")
         hi = np.searchsorted(parents_sorted, frontier, side="right")
-        nxt = np.concatenate([nodes[a:b] for a, b in zip(lo, hi)]) if frontier.size else frontier
+        nxt = nodes[_ranges(lo, hi)]
         nxt = nxt[level[nxt] < 0]
         level[nxt] = depth
         frontier = nxt
     return level
+
+
+def _edges_exist(g: CSRGraph, u: np.ndarray, v: np.ndarray) -> np.ndarray:
+    """Whether each ``(u, v)`` is an edge: a vectorized binary search of
+    ``v`` in row ``u`` of the CSR (``build_csr`` sorts every row)."""
+    lo, hi = g.row_ptr[u].copy(), g.row_ptr[u + 1]
+    end = hi.copy()
+    while True:
+        active = lo < hi
+        if not active.any():
+            break
+        mid = (lo + hi) // 2
+        right = g.col_idx[np.minimum(mid, g.m - 1)] < v
+        lo = np.where(active & right, mid + 1, lo)
+        hi = np.where(active & ~right, mid, hi)
+    return (lo < end) & (g.col_idx[np.minimum(lo, g.m - 1)] == v)
 
 
 def validate_bfs_tree(
@@ -81,12 +108,7 @@ def validate_bfs_tree(
     tree_v = np.nonzero(reached & (np.arange(n) != root))[0]
     tree_u = parent[tree_v]
     if tree_v.size:
-        # membership check via CSR binary search
-        starts, ends = g.row_ptr[tree_u], g.row_ptr[tree_u + 1]
-        exists = np.zeros(tree_v.size, dtype=bool)
-        for k in range(tree_v.size):
-            nbrs = g.col_idx[starts[k] : ends[k]]
-            exists[k] = np.any(nbrs == tree_v[k])
+        exists = _edges_exist(g, tree_u, tree_v)
         if not exists.all():
             failures.append(f"rule5: {int((~exists).sum())} tree edges missing from graph")
         dl = lv[tree_v] - lv[tree_u]
@@ -95,14 +117,15 @@ def validate_bfs_tree(
             failures.append(f"rule2: {int(bad.sum())} tree edges do not span exactly one level")
 
     # Rule 3: every graph edge spans <= 1 level, both-or-neither reached.
-    eu, ev = g.src.astype(np.int64), g.dst.astype(np.int64)
-    ru, rv = reached[eu], reached[ev]
-    if (ru != rv).any():
-        failures.append(f"rule4: {int((ru != rv).sum())} edges cross the reached boundary")
-    both = ru & rv
-    dl = np.abs(lv[eu[both]] - lv[ev[both]])
-    if (dl > 1).any():
-        failures.append(f"rule3: {int((dl > 1).sum())} graph edges span more than one level")
+    ru, rv = reached[g.src], reached[g.dst]
+    cross = int(np.count_nonzero(ru != rv))
+    if cross:
+        failures.append(f"rule4: {cross} edges cross the reached boundary")
+    lv32 = lv.astype(np.int32)  # levels are < n <= 2**31
+    span = np.abs(lv32[g.src] - lv32[g.dst]) > 1
+    far = int(np.count_nonzero(span & ru & rv))
+    if far:
+        failures.append(f"rule3: {far} graph edges span more than one level")
 
     # Rule 4: reached set == connected component of root (computed by ref BFS).
     comp = reference_bfs(g, root) >= 0
@@ -127,13 +150,11 @@ def reference_bfs(g: CSRGraph, root: int) -> np.ndarray:
     d = 0
     while frontier.size:
         d += 1
-        nbr_list = []
-        for v in frontier:
-            nbr_list.append(g.col_idx[g.row_ptr[v] : g.row_ptr[v + 1]])
-        nbrs = np.unique(np.concatenate(nbr_list)) if nbr_list else np.array([], np.int64)
-        nbrs = nbrs[level[nbrs] < 0]
-        level[nbrs] = d
-        frontier = nbrs
+        nbrs = g.col_idx[_ranges(g.row_ptr[frontier], g.row_ptr[frontier + 1])]
+        new = np.zeros(g.n, dtype=bool)
+        new[nbrs[level[nbrs] < 0]] = True
+        frontier = np.flatnonzero(new)
+        level[frontier] = d
     return level
 
 

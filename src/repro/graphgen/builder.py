@@ -19,7 +19,8 @@ class CSRGraph:
     Attributes:
       n: vertex count.
       row_ptr: (n+1,) int64 CSR offsets.
-      col_idx: (m,) int32 CSR adjacency (deduped, self-loop-free, symmetric).
+      col_idx: (m,) int32 CSR adjacency (deduped, self-loop-free, symmetric,
+        sorted within each row).
       src/dst: (m,) int32 COO view of the same edges (sorted by src).
       m_input: number of *input* (pre-dedup, directed) edges — the TEPS
         denominator uses input edges within the traversed component.
@@ -67,16 +68,17 @@ def build_csr(
     sym = symmetrize(edges) if symmetrize_edges else edges
     if drop_self_loops:
         sym = sym[sym[:, 0] != sym[:, 1]]
-    # Sort by (src, dst); dedupe.
-    order = np.lexsort((sym[:, 1], sym[:, 0]))
-    sym = sym[order]
-    if dedupe and sym.shape[0]:
-        keep = np.ones(sym.shape[0], dtype=bool)
-        keep[1:] = np.any(sym[1:] != sym[:-1], axis=1)
-        sym = sym[keep]
+    # Sort by (src, dst) as one int64 key (src * n + dst); dedupe.
+    key = sym[:, 0] * max(n, 1) + sym[:, 1]
+    del sym
+    key.sort()
+    if dedupe and key.size:
+        keep = np.ones(key.size, dtype=bool)
+        np.not_equal(key[1:], key[:-1], out=keep[1:])
+        key = key[keep]
 
-    src = sym[:, 0].astype(np.int32)
-    dst = sym[:, 1].astype(np.int32)
+    src = (key // max(n, 1)).astype(np.int32)
+    dst = (key % max(n, 1)).astype(np.int32)
     counts = np.bincount(src, minlength=n)
     row_ptr = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(counts, out=row_ptr[1:])
@@ -130,7 +132,7 @@ def ell_from_edges(
     ``n_cols``, which never hits a frontier bitmap); heavier rows keep all
     their edges in the returned COO residue — each row's edge set lives in
     exactly one structure, so ``min(slab result, residue result)`` equals
-    the flat segment_min over the union.  ``width`` (defaults to ``k``)
+    the flat minimum over the union.  ``width`` (defaults to ``k``)
     lets hybrid blocks share one slab width across blocks with different
     per-block splits.  Edges at the (``n_cols``, ``n_rows``) sentinels are
     dropped, mirroring how the gathers mask them.
@@ -149,7 +151,14 @@ def ell_from_edges(
     starts = np.searchsorted(sd, np.arange(n_rows))
     rank = np.arange(sd.size) - starts[sd]
     nbr[sd, rank] = ss
-    return nbr, s[~in_slab].astype(np.int32), d[~in_slab].astype(np.int32)
+    # the residue is destination-major (by destination, then source), the
+    # layout the COO expansion reads; partition blocks already are
+    rs, rd = s[~in_slab], d[~in_slab]
+    key = rd * (n_cols + 1) + rs
+    if (key[1:] < key[:-1]).any():
+        order = np.argsort(key, kind="stable")
+        rs, rd = rs[order], rd[order]
+    return nbr, rs.astype(np.int32), rd.astype(np.int32)
 
 
 def select_split_k(
@@ -197,7 +206,7 @@ def ell_graph_arrays(
 ) -> tuple[np.ndarray, int]:
     """Whole-graph ELL slab for the single-device driver.
 
-    ``k`` covers the heaviest row (rounded to the kernel's degree chunk),
+    ``k`` covers the heaviest row (rounded to a multiple of 8),
     so the residue is empty — the pure-ELL backend.  Returns (slab, k).
     """
     k = _round_up(max(int(edge_degrees(src, dst, n, n).max(initial=1)), 1),

@@ -15,10 +15,55 @@ see :func:`repro.graphgen.builder.relabel_by_degree`).
 
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
 
 # Graph500 quadrant probabilities.
 A, B, C, D = 0.57, 0.19, 0.19, 0.05
+
+
+def _rmat_bits(seed: int, scale: int, m: int, a: float, b: float, c: float,
+               lo: int, hi: int) -> np.ndarray:
+    """Endpoints of edges ``[lo, hi)``: bit ``ib`` of the row draws the
+    ``2 * ib``-th block of ``m`` uniforms, the column the next block, so a
+    chunk reads its slice of each block from an advanced copy of the
+    stream and the result does not depend on how edges are chunked."""
+    ab = a + b
+    c_norm = c / (1.0 - ab)
+    a_norm = a / ab
+    ij = np.zeros((2, hi - lo), dtype=np.int64)
+    for ib in range(scale):
+        draws = []
+        for block in (2 * ib, 2 * ib + 1):
+            bitgen = np.random.PCG64(seed)
+            bitgen.advance(block * m + lo)
+            draws.append(np.random.Generator(bitgen).random(hi - lo))
+        ii_bit = draws[0] > ab
+        jj_bit = draws[1] > np.where(ii_bit, c_norm, a_norm)
+        ij[0] += (1 << ib) * ii_bit
+        ij[1] += (1 << ib) * jj_bit
+    return ij
+
+
+def _rmat(scale: int, edgefactor: int, seed: int, a: float, b: float,
+          c: float) -> tuple[np.ndarray, np.random.Generator]:
+    """(2, m) RMAT endpoints, and the seed's generator advanced past the
+    bit draws (for the permutations that follow them).  Disjoint edge
+    chunks draw in threads; the output equals drawing each bit's ``m``
+    uniforms in turn from one stream."""
+    m = (1 << scale) * edgefactor
+    chunk = 1 << 20
+    with ThreadPoolExecutor(min(32, os.cpu_count() or 1)) as pool:
+        parts = pool.map(
+            lambda lo: _rmat_bits(seed, scale, m, a, b, c, lo, min(lo + chunk, m)),
+            range(0, m, chunk),
+        )
+        ij = np.concatenate(list(parts), axis=1)
+    rng = np.random.default_rng(seed)
+    rng.bit_generator.advance(2 * scale * m)
+    return ij, rng
 
 
 def kronecker_edges(
@@ -33,24 +78,11 @@ def kronecker_edges(
     half independently with the RMAT skew, then permute vertex labels and
     shuffle edge order.
     """
-    n = 1 << scale
-    m = n * edgefactor
-    rng = np.random.default_rng(seed)
-
-    ij = np.zeros((2, m), dtype=np.int64)
-    ab = A + B
-    c_norm = C / (1.0 - ab)
-    a_norm = A / ab
-    for ib in range(scale):
-        ii_bit = rng.random(m) > ab
-        jj_bit = rng.random(m) > np.where(ii_bit, c_norm, a_norm)
-        ij[0] += (1 << ib) * ii_bit
-        ij[1] += (1 << ib) * jj_bit
-
+    ij, rng = _rmat(scale, edgefactor, seed, A, B, C)
     if permute:
-        perm = rng.permutation(n)
+        perm = rng.permutation(1 << scale)
         ij = perm[ij]
-        ij = ij[:, rng.permutation(m)]
+        ij = ij[:, rng.permutation(ij.shape[1])]
     return ij.T.copy()
 
 
@@ -64,19 +96,8 @@ def rmat_edges(
     permute: bool = True,
 ) -> np.ndarray:
     """General RMAT with tunable skew (used by benchmarks to vary gap entropy)."""
-    n = 1 << scale
-    m = n * edgefactor
-    rng = np.random.default_rng(seed)
-    ab = a + b
-    c_norm = c / (1.0 - ab)
-    a_norm = a / ab
-    ij = np.zeros((2, m), dtype=np.int64)
-    for ib in range(scale):
-        ii_bit = rng.random(m) > ab
-        jj_bit = rng.random(m) > np.where(ii_bit, c_norm, a_norm)
-        ij[0] += (1 << ib) * ii_bit
-        ij[1] += (1 << ib) * jj_bit
+    ij, rng = _rmat(scale, edgefactor, seed, a, b, c)
     if permute:
-        perm = rng.permutation(n)
+        perm = rng.permutation(1 << scale)
         ij = perm[ij]
     return ij.T.copy()

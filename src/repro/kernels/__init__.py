@@ -1,14 +1,15 @@
-"""Pallas TPU kernels for the compute hot spots the paper optimizes.
+"""TPU kernels for the compute hot spots the paper optimizes.
 
 The paper's perf-critical layers: (a) the SIMD integer codec (its core
 contribution) -> ``bitpack``; (b) bitmap popcounts (§3.1) -> ``popcount``;
 (c) the SIMD-optimized SpMV inner loop (§6) -> ``spmv`` (ELL frontier
-expansion with VMEM-resident bitmap, push and pull directions).
-Beyond-paper: ``quant`` (int8 block quantization for gradient/payload
-compression).  Each kernel ships a ``pl.pallas_call`` + BlockSpec
-implementation, an ``ops.py`` jit'd wrapper and a ``ref.py`` pure-jnp
-oracle; tests sweep shapes/dtypes/densities against the oracles in
-interpret mode.
+expansion, push and pull directions).  Beyond-paper: ``quant`` (int8 block
+quantization for gradient/payload compression).  ``bitpack``, ``popcount``
+and ``quant`` each ship a ``pl.pallas_call`` + BlockSpec implementation,
+an ``ops.py`` jit'd wrapper and a ``ref.py`` pure-jnp oracle; tests sweep
+shapes/dtypes/densities against the oracles in interpret mode, and
+tests/test_chip_compile.py compiles them for v5e.  ``spmv`` is an XLA
+gather: Mosaic cannot lower its bitmap probe (see its ``ref`` module).
 """
 
 from __future__ import annotations

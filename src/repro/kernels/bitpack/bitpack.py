@@ -1,49 +1,69 @@
 """Pallas TPU kernel for vertical bit packing (S4-BP128 analog).
 
-Each grid step processes ``VALS_PER_BLOCK = 4096`` values — four 1024-value
-chunks laid out as a (32, 128) int32 tile in VMEM — and emits a ``(b, 128)``
-uint32 tile of packed words.  Every shift/OR acts on whole (8,128) vregs
-along the sublane axis; there is no cross-lane traffic for b >= 4 and only
-static in-tile reshapes for b in {1, 2} (see DESIGN.md §3).
+Layout (see :mod:`repro.kernels.bitpack.ref`): with ``K = 32 // b`` values
+per word and ``Wc = 32 * b`` words per 1024-value chunk, word ``j`` of chunk
+``c`` holds ``chunk_c[k*Wc + j]`` at bit offset ``k*b``.  The wrapper views
+the values as the ``(K, W)`` plane matrix ``X[k, c*Wc + j] = chunk_c[k*Wc + j]``
+(one XLA transpose of ``(C, K, Wc)``), so packing is an OR over the ``K``
+rows of ``X`` with static shifts and unpacking is its inverse.  Every
+shift/OR is a full-lane VPU op on a ``(K, TW)`` tile for every width class,
+b = 1 included: no lane shuffles, and every block spans the array's full
+second-minor dimension, which is what the TPU tiling rule asks of blocks
+with fewer than 8 rows.
 
 Validated in interpret mode against :mod:`repro.kernels.bitpack.ref` over a
-shape x bit-width sweep (tests/test_kernels.py).
+shape x bit-width sweep (tests/test_kernels.py) and compiled for v5e in
+tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import resolve_interpret
-from repro.kernels.bitpack.ref import B_CLASSES, CHUNK
+from repro.kernels.bitpack.ref import B_CLASSES
 
-VALS_PER_BLOCK = 4096  # 4 chunks = (32, 128) tile
-_ROWS_IN = VALS_PER_BLOCK // 128  # 32
+#: value-count alignment of the kernels: packed words then fill whole
+#: 128-lane rows at every width class (4096 * b / 32 = 128 * b words)
+VALS_PER_BLOCK = 4096
+#: most packed words (lanes) one grid step covers
+MAX_STEP_WORDS = 8192
 
 
-def _pack_kernel(v_ref, o_ref, *, b: int):
-    k_per_word = 32 // b
+def _step_words(w: int) -> int:
+    assert w % 128 == 0, w
+    return math.gcd(w, MAX_STEP_WORDS)
+
+
+def _to_planes(values: jax.Array, b: int) -> jax.Array:
+    k, wc = 32 // b, 32 * b
+    return values.reshape(-1, k, wc).transpose(1, 0, 2).reshape(k, -1)
+
+
+def _from_planes(x: jax.Array, b: int) -> jax.Array:
+    k, w = x.shape
     wc = 32 * b
-    v = v_ref[...].astype(jnp.uint32)  # (32, 128)
-    chunks = v.reshape(VALS_PER_BLOCK // CHUNK, k_per_word, wc)
-    out = jnp.zeros((VALS_PER_BLOCK // CHUNK, wc), dtype=jnp.uint32)
-    for k in range(k_per_word):
-        out = out | (chunks[:, k, :] << jnp.uint32(k * b))
-    o_ref[...] = out.reshape(b, 128)
+    return x.reshape(k, w // wc, wc).transpose(1, 0, 2).reshape(-1)
+
+
+def _pack_kernel(x_ref, o_ref, *, b: int):
+    x = x_ref[...]  # (K, TW) uint32
+    acc = x[0:1, :]
+    for k in range(1, 32 // b):
+        acc = acc | (x[k : k + 1, :] << jnp.uint32(k * b))
+    o_ref[...] = acc
 
 
 def _unpack_kernel(w_ref, o_ref, *, b: int):
-    k_per_word = 32 // b
-    wc = 32 * b
-    w = w_ref[...].astype(jnp.uint32).reshape(VALS_PER_BLOCK // CHUNK, 1, wc)
-    shifts = (jnp.arange(k_per_word, dtype=jnp.uint32) * b)[None, :, None]
-    mask = jnp.uint32((1 << b) - 1)
-    vals = (w >> shifts) & mask  # (4, K, wc)
-    o_ref[...] = vals.reshape(_ROWS_IN, 128)
+    w = w_ref[...]  # (1, TW) uint32
+    k_rows, tw = o_ref.shape
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (k_rows, tw), 0) * jnp.uint32(b)
+    o_ref[...] = (w >> shifts) & jnp.uint32((1 << b) - 1)
 
 
 @functools.partial(jax.jit, static_argnames=("b", "interpret"))
@@ -55,16 +75,16 @@ def pack_pallas(values: jax.Array, b: int, interpret: bool | None = None) -> jax
         return values.astype(jnp.uint32)
     n = values.shape[0]
     assert n % VALS_PER_BLOCK == 0, n
-    grid = n // VALS_PER_BLOCK
-    v2 = values.astype(jnp.uint32).reshape(n // 128, 128)
+    k, w = 32 // b, n * b // 32
+    tw = _step_words(w)
     out = pl.pallas_call(
         functools.partial(_pack_kernel, b=b),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((_ROWS_IN, 128), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((b, 128), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid * b, 128), jnp.uint32),
+        grid=(w // tw,),
+        in_specs=[pl.BlockSpec((k, tw), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, tw), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, w), jnp.uint32),
         interpret=interpret,
-    )(v2)
+    )(_to_planes(values.astype(jnp.uint32), b))
     return out.reshape(-1)
 
 
@@ -75,17 +95,16 @@ def unpack_pallas(words: jax.Array, b: int, interpret: bool | None = None) -> ja
     assert b in B_CLASSES, b
     if b == 32:
         return words.astype(jnp.uint32)
-    nw = words.shape[0]
-    words_per_block = VALS_PER_BLOCK * b // 32  # = 128*b
-    assert nw % words_per_block == 0, nw
-    grid = nw // words_per_block
-    w2 = words.astype(jnp.uint32).reshape(grid * b, 128)
-    out = pl.pallas_call(
+    w = words.shape[0]
+    assert (w * 32 // b) % VALS_PER_BLOCK == 0, w
+    k = 32 // b
+    tw = _step_words(w)
+    x = pl.pallas_call(
         functools.partial(_unpack_kernel, b=b),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((b, 128), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((_ROWS_IN, 128), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid * _ROWS_IN, 128), jnp.uint32),
+        grid=(w // tw,),
+        in_specs=[pl.BlockSpec((1, tw), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((k, tw), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, w), jnp.uint32),
         interpret=interpret,
-    )(w2)
-    return out.reshape(-1)
+    )(words.astype(jnp.uint32).reshape(1, w))
+    return _from_planes(x, b)

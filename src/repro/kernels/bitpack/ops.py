@@ -1,8 +1,11 @@
 """jit'd dispatch layer for the bitpack kernel.
 
-On TPU the Pallas kernel is used (compiled); elsewhere the pure-jnp oracle —
-the two are bit-identical (tests sweep shapes x widths).  The public API is
-what the compressed collectives call.
+On TPU the compiled Pallas kernel packs every stream: lengths off the
+kernel's 4096-value alignment are zero-padded with whole 1024-value chunks,
+whose words are sliced off again (chunks pack independently, so the padding
+never touches real words).  Elsewhere the pure-jnp oracle runs; the two are
+bit-identical (tests sweep shapes x widths).  The public API is what the
+compressed collectives call.
 """
 
 from __future__ import annotations
@@ -18,15 +21,20 @@ def _use_pallas() -> bool:
 
 
 def pack(values: jax.Array, b: int) -> jax.Array:
-    if _use_pallas() and values.shape[0] % bitpack.VALS_PER_BLOCK == 0:
-        return bitpack.pack_pallas(values, b)
-    return ref.pack(values, b)
+    if not _use_pallas():
+        return ref.pack(values, b)
+    n = values.shape[0]
+    assert n % ref.CHUNK == 0, n
+    pad = -n % bitpack.VALS_PER_BLOCK
+    return bitpack.pack_pallas(jnp.pad(values, (0, pad)), b)[: n * b // 32]
 
 
 def unpack(words: jax.Array, b: int) -> jax.Array:
-    if _use_pallas() and (words.shape[0] * 32 // b) % bitpack.VALS_PER_BLOCK == 0:
-        return bitpack.unpack_pallas(words, b)
-    return ref.unpack(words, b)
+    if not _use_pallas():
+        return ref.unpack(words, b)
+    w = words.shape[0]
+    pad = -w % (bitpack.VALS_PER_BLOCK * b // 32)
+    return bitpack.unpack_pallas(jnp.pad(words, (0, pad)), b)[: w * 32 // b]
 
 
 def pack_planes(values: jax.Array, b: int) -> jax.Array:
@@ -67,8 +75,15 @@ def compressed_words(capacity: int, b: int) -> int:
 def compact_ids(mask_bits: jax.Array, capacity: int, fill: int) -> tuple[jax.Array, jax.Array]:
     """Stream-compact a boolean membership vector into sorted ids + count.
 
-    jnp.nonzero with static ``size`` — jit-safe replacement for the GPU
-    warp-scan compaction the paper's CUDA kernel uses.
+    The jit-safe replacement for the GPU warp-scan compaction the paper's
+    CUDA kernel uses: the k-th id is where the running count of set bits
+    first reaches k — one prefix sum, then a binary search per output slot.
+    Nothing is scattered (``jnp.nonzero`` scatters every element into a
+    histogram, which serializes on the TPU).  Slots past the count hold
+    ``fill``.
     """
-    (ids,) = jnp.nonzero(mask_bits, size=capacity, fill_value=fill)
-    return ids.astype(jnp.int32), jnp.sum(mask_bits.astype(jnp.int32))
+    ranks = jnp.cumsum(mask_bits.astype(jnp.int32))
+    count = ranks[-1]
+    k = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+    ids = jnp.searchsorted(ranks, k, side="left").astype(jnp.int32)
+    return jnp.where(k <= count, ids, jnp.int32(fill)), count

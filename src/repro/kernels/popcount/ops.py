@@ -8,16 +8,8 @@ import jax.numpy as jnp
 from repro.kernels.popcount import popcount, ref
 
 
-def popcount_blocks(words: jax.Array) -> jax.Array:
-    """Per-block popcounts of a uint32 word stream (any length: the last
-    block is zero-padded to the kernel's 1024-word geometry)."""
-    pad = (-words.shape[0]) % popcount.WORDS_PER_BLOCK
-    if pad:
-        words = jnp.concatenate([words, jnp.zeros((pad,), words.dtype)])
-    if jax.default_backend() == "tpu":
-        return popcount.popcount_blocks_pallas(words)
-    blocks = words.reshape(-1, popcount.WORDS_PER_BLOCK)
-    return jnp.sum(ref.popcount_words(blocks), axis=1)
+def _use_pallas() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def popcount_planes(words: jax.Array) -> jax.Array:
@@ -26,7 +18,8 @@ def popcount_planes(words: jax.Array) -> jax.Array:
 
     The multi-source frontier counter: one call reduces every source plane's
     packed bitmap, blocking the Pallas grid over ``B x words`` instead of
-    looping the single-plane kernel per source.
+    looping over sources.  On TPU the compiled kernel always runs; elsewhere
+    the jnp oracle does.
     """
     b, w = words.shape
     pad = (-w) % popcount.WORDS_PER_BLOCK
@@ -34,8 +27,8 @@ def popcount_planes(words: jax.Array) -> jax.Array:
         words = jnp.concatenate(
             [words, jnp.zeros((b, pad), words.dtype)], axis=1
         )
-    if jax.default_backend() == "tpu":
-        return jnp.sum(popcount.popcount_planes_pallas(words), axis=1)
+    if _use_pallas():
+        return popcount.popcount_planes_pallas(words)
     return jnp.sum(ref.popcount_words(words), axis=1)
 
 

@@ -1,13 +1,18 @@
 """Pallas TPU kernel: blocked SWAR popcount with in-VMEM reduction.
 
-Grid step = (8, 128) uint32 tile -> one int32 partial count. The SWAR adds
-and the tree reduction happen in VMEM; HBM traffic is exactly one read of
-the bitmap plus a (grid,) int32 write.
+Grid = (planes, word tiles).  Each step reads a ``(TR, 128)`` uint32 tile,
+counts bits with SWAR arithmetic and folds the tile's rows into the plane's
+``(8, 128)`` int32 lane partials, which stay resident across the plane's
+word tiles (output revisiting).  HBM traffic is one read of the bitmaps
+plus one ``(8, 128)`` write per plane; the wrapper's final sum of 1024
+partials per plane is negligible.  Every block is a multiple of the
+``(8, 128)`` int32 tile, as the TPU lowering requires.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -17,60 +22,51 @@ from repro.kernels import resolve_interpret
 
 TILE = (8, 128)
 WORDS_PER_BLOCK = TILE[0] * TILE[1]
+#: most 128-word rows one grid step reads (256 KiB of uint32)
+MAX_STEP_ROWS = 512
 
 
 def _popcount_kernel(w_ref, o_ref):
-    v = w_ref[...].astype(jnp.uint32)
+    j = pl.program_id(1)
+    v = w_ref[...]
     v = v - ((v >> 1) & jnp.uint32(0x55555555))
     v = (v & jnp.uint32(0x33333333)) + ((v >> 2) & jnp.uint32(0x33333333))
     v = (v + (v >> 4)) & jnp.uint32(0x0F0F0F0F)
     counts = ((v * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
-    o_ref[...] = jnp.sum(counts).reshape(1)
+    part = jnp.sum(counts.reshape(-1, *TILE), axis=0)
 
+    @pl.when(j == 0)
+    def _init():
+        o_ref[...] = part
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def popcount_blocks_pallas(words: jax.Array, interpret: bool | None = None) -> jax.Array:
-    """Per-1024-word-block popcounts; words length % 1024 == 0."""
-    interpret = resolve_interpret(interpret)
-    n = words.shape[0]
-    assert n % WORDS_PER_BLOCK == 0, n
-    grid = n // WORDS_PER_BLOCK
-    w2 = words.astype(jnp.uint32).reshape(n // TILE[1], TILE[1])
-    return pl.pallas_call(
-        _popcount_kernel,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec(TILE, lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((grid,), jnp.int32),
-        interpret=interpret,
-    )(w2)
+    @pl.when(j > 0)
+    def _acc():
+        o_ref[...] += part
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def popcount_planes_pallas(
     words: jax.Array, interpret: bool | None = None
 ) -> jax.Array:
-    """Per-(plane, 1024-word-block) popcounts of a ``(B, W)`` word matrix.
+    """Per-plane popcounts of a ``(B, W)`` word matrix, ``W % 1024 == 0``.
 
-    The multi-source batch axis as a leading grid dimension: the grid blocks
-    over ``B x words`` so every plane's bitmap is reduced by the same SWAR
-    kernel without a host-side loop over sources.  ``W % 1024 == 0``;
-    returns ``(B, W // 1024)`` int32 partial counts (sum axis 1 for the
-    per-plane totals).
+    The multi-source batch axis is the leading grid dimension, so every
+    plane's bitmap is reduced by the same SWAR kernel in one launch.
+    Returns ``(B,)`` int32 totals.
     """
     interpret = resolve_interpret(interpret)
     b, w = words.shape
     assert w % WORDS_PER_BLOCK == 0, (b, w)
-    blocks = w // WORDS_PER_BLOCK
-    w2 = words.astype(jnp.uint32).reshape(b * w // TILE[1], TILE[1])
+    rows = w // TILE[1]
+    tr = math.gcd(rows, MAX_STEP_ROWS)
+    steps = rows // tr
+    w2 = words.astype(jnp.uint32).reshape(b * rows, TILE[1])
     out = pl.pallas_call(
         _popcount_kernel,
-        grid=(b, blocks),
-        in_specs=[
-            pl.BlockSpec(TILE, lambda i, j, _bl=blocks: (i * _bl + j, 0))
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i, j, _bl=blocks: (i * _bl + j,)),
-        out_shape=jax.ShapeDtypeStruct((b * blocks,), jnp.int32),
+        grid=(b, steps),
+        in_specs=[pl.BlockSpec((tr, TILE[1]), lambda p, j: (p * steps + j, 0))],
+        out_specs=pl.BlockSpec(TILE, lambda p, j: (p, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * TILE[0], TILE[1]), jnp.int32),
         interpret=interpret,
     )(w2)
-    return out.reshape(b, blocks)
+    return jnp.sum(out.reshape(b, -1), axis=1)

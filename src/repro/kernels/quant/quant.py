@@ -1,8 +1,10 @@
 """Pallas TPU kernel: fused block-quantize (max-abs scale + round to int8).
 
-Grid step = (8, 128) float32 tile -> (8, 128) int8 tile + (8,) row scales.
-The reduction (max-abs) and the elementwise scale/round stay in VMEM; on TPU
-this is one VPU pass instead of XLA's reduce + broadcast + round chain.
+Grid step = ``(ROWS, 128)`` float32 tile -> ``(ROWS, 128)`` int8 tile +
+``(ROWS, 1)`` row scales.  ``ROWS = 32`` is the int8 sublane tile, and the
+scales leave as a column so that no block is rank 1.  The reduction
+(max-abs) and the elementwise scale/round stay in VMEM; on TPU this is one
+VPU pass instead of XLA's reduce + broadcast + round chain.
 """
 
 from __future__ import annotations
@@ -16,14 +18,14 @@ from jax.experimental import pallas as pl
 from repro.kernels import resolve_interpret
 from repro.kernels.quant.ref import GROUP
 
-ROWS = 8  # rows per grid step
+ROWS = 32  # rows per grid step: the int8 (32, 128) tile
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)  # (8, 128)
-    scale = jnp.max(jnp.abs(x), axis=1) / 127.0  # (8,)
+    x = x_ref[...]  # (ROWS, 128) float32
+    scale = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0  # (ROWS, 1)
     safe = jnp.where(scale > 0, scale, 1.0)
-    q_ref[...] = jnp.clip(jnp.round(x / safe[:, None]), -127, 127).astype(jnp.int8)
+    q_ref[...] = jnp.clip(jnp.round(x / safe), -127, 127).astype(jnp.int8)
     s_ref[...] = scale
 
 
@@ -40,12 +42,12 @@ def quantize_pallas(x: jax.Array, interpret: bool | None = None) -> tuple[jax.Ar
         in_specs=[pl.BlockSpec((ROWS, GROUP), lambda i: (i, 0))],
         out_specs=(
             pl.BlockSpec((ROWS, GROUP), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS,), lambda i: (i,)),
+            pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((n // GROUP, GROUP), jnp.int8),
-            jax.ShapeDtypeStruct((n // GROUP,), jnp.float32),
+            jax.ShapeDtypeStruct((n // GROUP, 1), jnp.float32),
         ),
         interpret=interpret,
     )(x2)
-    return q.reshape(-1), s
+    return q.reshape(-1), s.reshape(-1)

@@ -1,14 +1,12 @@
-"""Blocked ELL SpMV kernels: the BFS frontier-expansion hot spot.
+"""Blocked ELL SpMV: the BFS frontier-expansion hot spot.
 
 The paper's §6 hand-optimizes exactly this loop with CPU SIMD (strength
-reduction, vectorization of the matrix iteration).  The TPU analog: the
-destination-major ELL neighbor tile streams through VMEM, the frontier
-bitmap stays VMEM-resident, and the candidate-parent min-reduction runs on
-the VPU — one (8,128) tile of destinations per grid step per degree chunk.
-
-Two directions (Beamer, paper §3.1): ``spmv`` is the push (top-down)
-kernel; ``pull`` is the bottom-up kernel, where only unreached rows probe
-and a second resident bitmap masks finished destinations.
+reduction, vectorization of the matrix iteration).  Here the
+destination-major ELL neighbor slab probes the packed frontier bitmap
+through XLA's gather and min-reduces candidate parents per row, in both
+directions (Beamer, paper §3.1): push, and pull, where only unreached
+rows produce candidates.  See :mod:`repro.kernels.spmv.ref` for why this
+is not a Pallas kernel.
 """
 
-from repro.kernels.spmv import ops, pull, ref  # noqa: F401
+from repro.kernels.spmv import ref  # noqa: F401

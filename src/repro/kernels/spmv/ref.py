@@ -1,6 +1,6 @@
-"""Pure-jnp oracle for the ELL frontier-expansion SpMV.
+"""ELL frontier-expansion SpMV: the XLA formulation on every backend.
 
-One parameterized reference — :func:`gspmm` (the DGL op x reduce shape) —
+One parameterized function — :func:`gspmm` (the DGL op x reduce shape) —
 stands behind *every* entry point in this package: the min-parent BFS
 semantics
 
@@ -9,8 +9,12 @@ semantics
 is its ``message=None, reduce=None`` instantiation, the pull direction is
 the same call with an unreached row mask, and the frontier-algebra value
 expansions (SSSP min-plus, CC label copy, PageRank plus-times) pass their
-own message/reduce closures.  The Pallas kernels are oracle-checked
-against this one function.
+own message/reduce closures.
+
+This is the production path on TPU as well as the CPU one.  The frontier
+probe is a data-dependent gather from the packed bitmap, and Mosaic does
+not lower a vector gather from VMEM at arbitrary indices ("Cannot do int
+indexing on TPU"), so the ELL slab expands through XLA's gather.
 
 ``nbr``: (n_rows, max_deg) int32 destination-major neighbor lists, padded
 with ``n_cols`` (which always misses the frontier).  ``frontier``: bitmap
@@ -104,7 +108,7 @@ def spmv_min(nbr: jax.Array, f_words: jax.Array, n_cols: int) -> jax.Array:
 def spmv_min_planes(nbr: jax.Array, f_words: jax.Array, n_cols: int) -> jax.Array:
     """Multi-source push expansion: ``f_words`` is ``(B, n_cols/32)`` packed
     frontier planes -> ``(B, n_rows)`` per-plane min frontier neighbors."""
-    return jax.vmap(lambda fw: spmv_min(nbr, fw, n_cols))(f_words)
+    return jax.lax.map(lambda fw: spmv_min(nbr, fw, n_cols), f_words)
 
 
 def spmv_pull_min_planes(
@@ -112,8 +116,8 @@ def spmv_pull_min_planes(
 ) -> jax.Array:
     """Multi-source pull expansion: ``(B, n_cols/32)`` frontier planes and
     ``(B, n_rows/32)`` unreached planes -> ``(B, n_rows)`` per-plane mins."""
-    return jax.vmap(lambda fw, uw: spmv_pull_min(nbr, fw, uw, n_cols))(
-        f_words, u_words
+    return jax.lax.map(
+        lambda a: spmv_pull_min(nbr, a[0], a[1], n_cols), (f_words, u_words)
     )
 
 
@@ -128,3 +132,43 @@ def spmv_pull_min(
     neither need a parent nor should pay for the probe on hardware).
     """
     return gspmm(nbr, f_words, n_cols, u_words=u_words)
+
+
+def gspmm_planes(
+    nbr: jax.Array,
+    f_words: jax.Array,
+    x: jax.Array,
+    n_cols: int,
+    alg,
+    *,
+    row_base=0,
+    col_base=0,
+    u_words: jax.Array | None = None,
+) -> jax.Array:
+    """op x reduce ELL value expansion over (B,) frontier/value planes.
+
+    Each frontier hit proposes ``alg.edge_message(x[src], src + col_base,
+    dst + row_base)``; candidates combine per destination row under the
+    algebra's reduce (min, or a decode-add-encode sum).  ``u_words``, if
+    given, masks finished destination rows to the algebra's empty sentinel
+    (pull direction).
+    """
+    n_x = x.shape[1]
+    if alg.reduce == "min":
+        reduce = None
+    else:
+        reduce = lambda vals, axis: alg.enc(jnp.sum(alg.dec(vals), axis=axis))  # noqa: E731
+
+    def one(fw, xp, uw):
+        def message(rows, cols):
+            xs = xp[jnp.minimum(cols, n_x - 1)]
+            return alg.edge_message(xs, cols + col_base, rows + row_base)
+
+        return gspmm(
+            nbr, fw, n_cols, message=message, reduce=reduce,
+            empty=alg.empty, u_words=uw,
+        )
+
+    if u_words is None:
+        return jax.lax.map(lambda a: one(*a, None), (f_words, x))
+    return jax.lax.map(lambda a: one(*a), (f_words, x, u_words))

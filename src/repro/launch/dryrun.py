@@ -32,7 +32,6 @@ import traceback
 
 import jax
 
-from repro import compat
 from repro.launch import cells as cellslib
 from repro.launch import mesh as meshlib
 from repro.launch import roofline
@@ -63,7 +62,7 @@ def run_cell(
             for k, v in cell.meta.items()
         }
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings)
-        with compat.set_mesh(mesh):  # bare-PartitionSpec constraints need a mesh
+        with jax.set_mesh(mesh):  # bare-PartitionSpec constraints need a mesh
             lowered = jitted.lower(*cell.args)
         rec["lower_s"] = round(time.time() - t0, 1)
         t1 = time.time()

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.csr import Partition2D
 from repro.kernels.quant import ref as quant
 
@@ -330,7 +329,7 @@ def build_2d_train_step(
     own = P(*dcfg.row_axes, dcfg.col_axis, None)
     own_flat = P(*dcfg.row_axes, dcfg.col_axis)
     in_specs = (P(), own, own, own, own, own_flat)
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,

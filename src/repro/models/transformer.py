@@ -407,6 +407,21 @@ def _dense_ffn(cfg, lp, x):
 
 def _moe_ffn(cfg: TransformerConfig, lp: Params, x):
     """GShard capacity dispatch with fine-grained routing groups."""
+    types = jax.sharding.get_abstract_mesh().axis_types
+    if cfg.moe_dp_axes and types and all(
+        t == jax.sharding.AxisType.Explicit for t in types
+    ):
+        # the dispatch pins are Auto-mode sharding hints (under Explicit
+        # axes ``with_sharding_constraint`` only asserts): run the body
+        # with the mesh axes switched to Auto
+        out = (jax.typeof(x).sharding.spec, P())
+        return jax.sharding.auto_axes(
+            functools.partial(_moe_ffn_body, cfg), out_sharding=out
+        )(lp, x)
+    return _moe_ffn_body(cfg, lp, x)
+
+
+def _moe_ffn_body(cfg: TransformerConfig, lp: Params, x):
     cdt = cfg.compute_dtype
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k

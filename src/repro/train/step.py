@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.optim import adamw, grad_compress
 
 
@@ -79,7 +78,7 @@ def make_dp_train_step(
 
     rep = P()
     batch_spec = P(dp_axis)
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(rep, batch_spec),

@@ -104,6 +104,27 @@ def test_validator_catches_corruption():
         assert not validate.validate_bfs_tree(g, bad, 0).ok
 
 
+def test_edge_membership_and_reference_bfs_vectorized():
+    """The vectorized host checks agree with per-vertex python loops: the
+    CSR edge lookup behind rule 5, and the reference BFS levels."""
+    g = builder.build_csr(kronecker.kronecker_edges(9, seed=5), n=512)
+    rng = np.random.default_rng(0)
+    u = rng.integers(0, g.n, 4000)
+    v = np.where(rng.random(4000) < 0.5, rng.integers(0, g.n, 4000),
+                 g.col_idx[np.minimum(g.row_ptr[u], g.m - 1)])
+    expect = np.array([b in set(g.neighbors(a).tolist()) for a, b in zip(u, v)])
+    np.testing.assert_array_equal(validate._edges_exist(g, u, v), expect)
+    root = int(np.argmax(g.degrees()))
+    level = np.full(g.n, -1)
+    level[root], frontier, d = 0, [root], 0
+    while frontier:
+        d += 1
+        nxt = sorted({int(w) for x in frontier for w in g.neighbors(x) if level[w] < 0})
+        level[nxt] = d
+        frontier = nxt
+    np.testing.assert_array_equal(validate.reference_bfs(g, root), level)
+
+
 def test_traversed_edges_teps_numerator():
     g = builder.build_csr(kronecker.kronecker_edges(8, seed=1), n=256)
     src, dst = _device_graph(g)

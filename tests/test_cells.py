@@ -60,7 +60,6 @@ def test_perf_variants_lower():
     out = _run(
         """
 import jax
-from repro import compat
 from repro.launch import cells
 mesh = jax.make_mesh((2, 2), ("data", "model"))
 for arch, shape, variant in [
@@ -70,7 +69,7 @@ for arch, shape, variant in [
     ("graph500", "scale30", "ecap15-bitmaponly"),
 ]:
     cell = cells.build_cell(arch, shape, mesh, variant=variant)
-    with compat.set_mesh(mesh):  # bare-P sharding constraints need a mesh
+    with jax.set_mesh(mesh):  # bare-P sharding constraints need a mesh
         jax.jit(cell.fn, in_shardings=cell.in_shardings).lower(*cell.args)
 print("VARIANTS OK")
 """,

@@ -182,8 +182,6 @@ def test_engine_ppermute_identity_pairs_move_nothing():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     mesh = jax.make_mesh((1,), ("x",))
     stats = comm.CommStats()
 
@@ -191,7 +189,8 @@ def test_engine_ppermute_identity_pairs_move_nothing():
         ex = comm.AdaptiveExchange("bfs/transpose", "x", 1, None, stats)
         return ex.ppermute(x.reshape(-1), [(0, 0)], fmt="membership")
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P()))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                              check_vma=False))
     np.testing.assert_array_equal(
         np.asarray(f(jnp.arange(64, dtype=jnp.int32))), np.arange(64)
     )
@@ -207,7 +206,6 @@ def test_butterfly_stage_collectives_single_rank():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.comm import butterfly
     from repro.comm import collectives as cc_new
 
@@ -233,7 +231,8 @@ def test_butterfly_stage_collectives_single_rank():
                 )
 
             f = jax.jit(
-                compat.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
+                jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                              check_vma=False)
             )
             out = np.asarray(f(jnp.asarray(block_np)))
             np.testing.assert_array_equal(
@@ -252,7 +251,8 @@ def test_butterfly_stage_collectives_single_rank():
                 )
 
             fm = jax.jit(
-                compat.shard_map(body_m, mesh=mesh, in_specs=P(), out_specs=P())
+                jax.shard_map(body_m, mesh=mesh, in_specs=P(), out_specs=P(),
+                              check_vma=False)
             )
             np.testing.assert_array_equal(
                 np.asarray(fm(jnp.asarray(bits_np))), bits_np,
@@ -270,8 +270,6 @@ def test_adaptive_exchange_bucket_choice_monotone():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     s = 1 << 16
     ladder = comm.BucketLadder.default(s, floor_words=s)
     mesh = jax.make_mesh((1,), ("x",))
@@ -279,14 +277,14 @@ def test_adaptive_exchange_bucket_choice_monotone():
 
     def which_branch(bits):
         ex = comm.AdaptiveExchange("test", "x", 1, ladder, stats)
-        _, count, exc = comm.stream_stats(bits, s)
+        count, exc = comm.stream_stats(bits)
         branches = [
             functools.partial(lambda i, _: jnp.int32(i), i)
             for i in range(ladder.n_branches)
         ]
         return ex.dispatch(ladder.bucket_for(count, exc), branches)
 
-    f = jax.jit(compat.shard_map(which_branch, mesh=mesh, in_specs=P(), out_specs=P()))
+    f = jax.jit(jax.shard_map(which_branch, mesh=mesh, in_specs=P(), out_specs=P()))
     rng = np.random.default_rng(0)
     prev = 0
     for density in (0.002, 0.02, 0.1, 0.6):
@@ -295,3 +293,24 @@ def test_adaptive_exchange_bucket_choice_monotone():
         prev = b
     assert prev == len(ladder.specs)  # densest input -> dense fallback
     assert any(r.fmt == "consensus" for r in stats.records())
+
+
+def test_stream_stats_match_compacted_gaps():
+    """Counts and 16-bit gap exceptions from running maxima equal those of
+    the compacted stream's gaps, per plane of a batch."""
+    from repro.kernels.bitpack import ops as bpops
+    from repro.kernels.bitpack import ref as bpref
+
+    s = 1 << 18
+    rng = np.random.default_rng(3)
+    bits = np.zeros((4, s), bool)
+    bits[0, [0, 70000, 70001, 200000]] = True  # first id at 0, two wide gaps
+    bits[1, rng.integers(0, s, 40)] = True
+    bits[2] = rng.random(s) < 0.2
+    counts, excs = comm.stream_stats(jnp.asarray(bits))
+    for p in range(4):
+        ids, count = bpops.compact_ids(jnp.asarray(bits[p]), s, fill=s)
+        gaps = np.asarray(bpref.gaps_from_sorted(ids, count))
+        assert int(counts[p]) == int(count) == bits[p].sum()
+        assert int(excs[p]) == int(((gaps >> 16) > 0).sum())
+    assert int(excs[0]) == 2 and int(excs[3]) == 0

@@ -184,9 +184,8 @@ mesh = jax.make_mesh((4,), ("data",))
 s = 65536
 ladder = cc.BucketLadder.default(s)
 assert ladder.specs, "ladder must have sparse buckets at s=65536"
-from repro import compat
 def gathered(bits):
-    f = compat.shard_map(lambda b: cc.allgather_membership(b.reshape(-1), ("data",), ladder, 4),
+    f = jax.shard_map(lambda b: cc.allgather_membership(b.reshape(-1), ("data",), ladder, 4),
                   mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
     return jax.jit(f)(bits)
 rng = np.random.default_rng(0)
@@ -245,9 +244,8 @@ from repro import comm as cc
 mesh = jax.make_mesh((4,), ("data",))
 s = 2048
 ladder = cc.BucketLadder.default(s)
-from repro import compat
 def gathered(bits):
-    f = compat.shard_map(lambda b: cc.allgather_membership(b.reshape(-1), ("data",), ladder, 4),
+    f = jax.shard_map(lambda b: cc.allgather_membership(b.reshape(-1), ("data",), ladder, 4),
                   mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
     return jax.jit(f)(bits)
 rng = np.random.default_rng(0)

@@ -24,7 +24,6 @@ from repro.comm import registry as wire_registry
 from repro.core import bfs, expand
 from repro.graphgen import builder, kronecker
 from repro.kernels.bitpack import ref as bpref
-from repro.kernels.spmv import ops as spmv_ops
 from repro.kernels.spmv import ref as spmv_ref
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -127,12 +126,55 @@ def _python_spmv_oracle(nbr, bits, n_cols):
     return out
 
 
+@pytest.mark.parametrize("density", [0.0, 0.02, 0.3, 1.0])
+def test_coo_first_hit_matches_segment_min(density):
+    """On a destination-major block (as ``partition_2d`` lays it out), the
+    first-hit scan equals the scatter formulation: push per destination,
+    pull masked to unreached rows, and the degree vector from row offsets."""
+    from repro.core import csr as csrmod
+    from repro.core import traversal
+
+    g = builder.build_csr(kronecker.kronecker_edges(10, seed=4), n=1 << 10)
+    bg = csrmod.partition_2d(g, rows=2, cols=2, chunk_multiple=64)
+    part = bg.part
+    rng = np.random.default_rng(int(density * 100))
+    backend = expand.resolve("coo")
+    for i in range(2):
+        for j in range(2):
+            src, dst = bg.src_local[i, j], bg.dst_local[i, j]
+            key = dst.astype(np.int64) * (part.n_c + 1) + src
+            assert (np.diff(key) >= 0).all()  # destination-major, sentinels last
+            f = rng.random((2, part.n_c)) < density
+            un = rng.random((2, part.n_r)) < 0.5
+            valid = (src < part.n_c) & (dst < part.n_r)
+            want = np.full((2, part.n_r + 1), spmv_ref.INF, np.int64)
+            for p in range(2):
+                hit = valid & f[p][np.minimum(src, part.n_c - 1)]
+                np.minimum.at(want[p], dst[hit], src[hit])
+            want = want[:, : part.n_r]
+            blk = backend.local_block(jnp.asarray(src), jnp.asarray(dst), (),
+                                      part.n_r, part.n_c)
+            push = backend.push_planes(blk, jnp.asarray(f))
+            np.testing.assert_array_equal(np.asarray(push), want)
+            pull = backend.pull_planes(blk, jnp.asarray(f), jnp.asarray(un))
+            np.testing.assert_array_equal(
+                np.asarray(pull), np.where(un, want, spmv_ref.INF)
+            )
+            deg = traversal.degree_vector(jnp.asarray(dst), part.n_r)
+            np.testing.assert_array_equal(
+                np.asarray(deg), np.bincount(dst[valid], minlength=part.n_r)
+            )
+    # no sentinel tail: empty rows past the last edge stay empty
+    blk = backend.local_block(jnp.asarray([2, 5], jnp.int32),
+                              jnp.asarray([0, 0], jnp.int32), (), 3, 8)
+    push = backend.push_planes(blk, jnp.ones((1, 8), bool))
+    np.testing.assert_array_equal(np.asarray(push), [[2, spmv_ref.INF, spmv_ref.INF]])
+
+
 def test_spmv_ops_pad_misaligned_shapes():
-    """Satellite regression: the ops dispatch used to fall silently to the
-    interpret-speed reference on any block off the ROW_TILE/DEG_CHUNK
-    multiples — it now pads rows (sentinel neighbor lists) and degree
-    (sentinel slots) and slices the output.  ``interpret=True`` forces the
-    Pallas path so the padding wrapper is exercised off-TPU too."""
+    """Blocks off any tile multiple (rows, degree and an unreached bitmap
+    padded to the 1024-bit chunk) expand exactly through every entry point
+    of the XLA ELL SpMV, single-plane and plane-batched."""
     n_rows, max_deg, n_cols = 1500, 9, 2048  # deliberately misaligned
     rng = np.random.default_rng(7)
     nbr = rng.integers(0, n_cols, size=(n_rows, max_deg)).astype(np.int32)
@@ -144,23 +186,18 @@ def test_spmv_ops_pad_misaligned_shapes():
     u_bits[:n_rows] = unreached
     u_words = bpref.pack(jnp.asarray(u_bits), 1)
     expect = _python_spmv_oracle(nbr, bits, n_cols)
-    out = spmv_ops.spmv_min(jnp.asarray(nbr), f_words, n_cols, interpret=True)
+    out = spmv_ref.spmv_min(jnp.asarray(nbr), f_words, n_cols)
     assert out.shape == (n_rows,)
     np.testing.assert_array_equal(np.asarray(out), expect)
-    out = spmv_ops.spmv_pull_min(
-        jnp.asarray(nbr), f_words, u_words, n_cols, interpret=True
-    )
+    out = spmv_ref.spmv_pull_min(jnp.asarray(nbr), f_words, u_words, n_cols)
     np.testing.assert_array_equal(
         np.asarray(out), np.where(unreached, expect, spmv_ref.INF)
     )
-    # plane-batched entry points pad the same way
-    outp = spmv_ops.spmv_min_planes(
-        jnp.asarray(nbr), f_words[None], n_cols, interpret=True
-    )
+    outp = spmv_ref.spmv_min_planes(jnp.asarray(nbr), f_words[None], n_cols)
     assert outp.shape == (1, n_rows)
     np.testing.assert_array_equal(np.asarray(outp[0]), expect)
-    outp = spmv_ops.spmv_pull_min_planes(
-        jnp.asarray(nbr), f_words[None], u_words[None], n_cols, interpret=True
+    outp = spmv_ref.spmv_pull_min_planes(
+        jnp.asarray(nbr), f_words[None], u_words[None], n_cols
     )
     np.testing.assert_array_equal(
         np.asarray(outp[0]), np.where(unreached, expect, spmv_ref.INF)

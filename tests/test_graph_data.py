@@ -102,3 +102,22 @@ def test_molecule_batch_block_diagonal():
     assert gb.src.shape == (1024,)
     # edges never cross molecule boundaries
     assert np.array_equal(gb.src // 30, gb.dst // 30)
+
+
+def test_kronecker_threaded_draws_equal_one_stream():
+    """Edge chunks drawn in threads from advanced PCG64 copies give the
+    Graph500 octave reference's edges: each bit's ``m`` row uniforms, then
+    its ``m`` column uniforms, from one stream (scale 17 spans 2 chunks)."""
+    scale, ef, seed = 17, 16, 9
+    n, m = 1 << scale, (1 << scale) * ef
+    rng = np.random.default_rng(seed)
+    ab = kronecker.A + kronecker.B
+    ij = np.zeros((2, m), np.int64)
+    for ib in range(scale):
+        ii = rng.random(m) > ab
+        jj = rng.random(m) > np.where(ii, kronecker.C / (1 - ab), kronecker.A / ab)
+        ij[0] += (1 << ib) * ii
+        ij[1] += (1 << ib) * jj
+    perm = rng.permutation(n)
+    want = perm[ij][:, rng.permutation(m)].T
+    np.testing.assert_array_equal(kronecker.kronecker_edges(scale, ef, seed=seed), want)

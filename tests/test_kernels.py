@@ -10,7 +10,7 @@ from hypothesis import strategies as st
 
 from repro.kernels.bitpack import bitpack, ops as bpops, ref as bpref
 from repro.kernels.popcount import ops as pcops, popcount, ref as pcref
-from repro.kernels.quant import quant, ref as qref
+from repro.kernels.quant import ops as qops, quant, ref as qref
 
 
 @pytest.mark.parametrize("b", bpref.B_CLASSES)
@@ -86,26 +86,25 @@ def test_popcount_matches_python():
     words = rng.integers(0, 1 << 32, size=2048, dtype=np.uint64).astype(np.uint32)
     expect = np.array([bin(int(w)).count("1") for w in words])
     np.testing.assert_array_equal(np.asarray(pcref.popcount_words(jnp.asarray(words))), expect)
-    blocks = np.asarray(popcount.popcount_blocks_pallas(jnp.asarray(words)))
-    np.testing.assert_array_equal(blocks, expect.reshape(2, 1024).sum(1))
+    total = np.asarray(popcount.popcount_planes_pallas(jnp.asarray(words[None])))
+    np.testing.assert_array_equal(total, [expect.sum()])
     np.testing.assert_array_equal(
-        np.asarray(pcops.popcount_blocks(jnp.asarray(words))), expect.reshape(2, 1024).sum(1)
+        np.asarray(pcops.popcount_planes(jnp.asarray(words[None]))), [expect.sum()]
     )
 
 
 def test_popcount_planes_matches_per_plane_blocks():
-    """The plane-blocked kernel (one grid over B x words) equals the
-    single-plane kernel applied per source, including the zero-padding path
-    for word counts off the 1024-word block."""
+    """The plane-blocked kernel (one grid over B x word tiles, each plane's
+    lane partials accumulated across its tiles) equals the per-plane sum of
+    per-word counts, including the zero-padding path for word counts off
+    the 1024-word block."""
     rng = np.random.default_rng(1)
-    words = rng.integers(0, 1 << 32, size=(3, 2048), dtype=np.uint64).astype(np.uint32)
-    per_plane = np.stack(
-        [np.asarray(popcount.popcount_blocks_pallas(jnp.asarray(p))) for p in words]
-    )
+    # 3072 words = 24 rows of 128 -> three 8-row steps per plane
+    words = rng.integers(0, 1 << 32, size=(3, 3072), dtype=np.uint64).astype(np.uint32)
+    totals = np.asarray(pcref.popcount_words(jnp.asarray(words))).sum(axis=1)
     np.testing.assert_array_equal(
-        np.asarray(popcount.popcount_planes_pallas(jnp.asarray(words))), per_plane
+        np.asarray(popcount.popcount_planes_pallas(jnp.asarray(words))), totals
     )
-    totals = per_plane.sum(axis=1)
     np.testing.assert_array_equal(
         np.asarray(pcops.popcount_planes(jnp.asarray(words))), totals
     )
@@ -137,3 +136,60 @@ def test_compact_ids():
     assert int(count) == 4
     np.testing.assert_array_equal(np.asarray(ids)[:4], [1, 2, 4, 7])
     assert np.all(np.asarray(ids)[4:] == 8)
+
+
+@pytest.mark.parametrize("density,capacity", [(0.0, 64), (0.01, 64), (0.3, 256), (0.9, 4096)])
+def test_compact_ids_matches_nonzero(density, capacity):
+    """The scan-and-search compaction equals ``jnp.nonzero`` with a static
+    size, truncation past the capacity included."""
+    rng = np.random.default_rng(int(density * 100) + capacity)
+    mask = rng.random(4096) < density
+    ids, count = bpops.compact_ids(jnp.asarray(mask), capacity=capacity, fill=4096)
+    (want,) = np.nonzero(mask)
+    want = np.concatenate([want, np.full(capacity, 4096)])[:capacity]
+    assert int(count) == mask.sum()
+    np.testing.assert_array_equal(np.asarray(ids), want)
+
+
+@pytest.mark.parametrize("b", [1, 4, 16])
+def test_bitpack_ops_pad_to_kernel_alignment(monkeypatch, b):
+    """The TPU branch of the dispatch packs streams off the kernel's
+    4096-value alignment by padding whole chunks, never by falling back to
+    the jnp reference (the kernel runs in interpret mode here)."""
+    monkeypatch.setattr(bpops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(bpref, "pack", None)  # the reference must not run
+    n = 3 * bpref.CHUNK
+    rng = np.random.default_rng(b)
+    vals = rng.integers(0, 1 << b, size=n, dtype=np.uint64).astype(np.uint32)
+    words = bpops.pack(jnp.asarray(vals), b)
+    assert words.shape == (n * b // 32,)
+    np.testing.assert_array_equal(np.asarray(bpops.unpack(words, b)), vals)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(
+        np.asarray(words), np.asarray(bpref.pack(jnp.asarray(vals), b))
+    )
+
+
+def test_quant_ops_pad_to_kernel_step(monkeypatch):
+    """The TPU branch quantizes payloads off the kernel's ROWS x GROUP step
+    by zero-padding, and matches the oracle on the real values."""
+    monkeypatch.setattr(qops, "_use_pallas", lambda: True)
+    x = (np.random.default_rng(3).normal(size=5 * qref.GROUP) * 4).astype(np.float32)
+    q, s = qops.quantize(jnp.asarray(x))
+    q_ref, s_ref = qref.quantize(jnp.asarray(x))
+    assert q.shape == q_ref.shape and s.shape == s_ref.shape
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(q_ref))
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), rtol=1e-6)
+
+
+def test_popcount_ops_kernel_branch_pads(monkeypatch):
+    """The TPU branch of ``popcount_planes`` pads planes off the 1024-word
+    block and runs the kernel."""
+    monkeypatch.setattr(pcops, "_use_pallas", lambda: True)
+    words = np.random.default_rng(4).integers(
+        0, 1 << 32, size=(2, 1500), dtype=np.uint64
+    ).astype(np.uint32)
+    np.testing.assert_array_equal(
+        np.asarray(pcops.popcount_planes(jnp.asarray(words))),
+        np.asarray(pcref.popcount_words(jnp.asarray(words))).sum(axis=1),
+    )

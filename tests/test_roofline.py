@@ -76,8 +76,7 @@ from repro.launch import roofline
 mesh = jax.make_mesh((4,), ("data",))
 def f(x):
     return jax.lax.psum(x * 2, "data")
-from repro import compat
-m = compat.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)
+m = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)
 compiled = jax.jit(m).lower(jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
 stats = roofline.parse_collectives(compiled.as_text())
 assert stats.n_ops >= 1, compiled.as_text()[:500]

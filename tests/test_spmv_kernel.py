@@ -1,4 +1,5 @@
-"""ELL SpMV kernel (frontier expansion) vs oracles — shape/density sweeps."""
+"""ELL SpMV (frontier expansion, the XLA gather formulation every backend
+runs) vs a python loop oracle — shape/density sweeps."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -7,7 +8,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.kernels.bitpack import ref as bpref
-from repro.kernels.spmv import ops, pull, ref, spmv
+from repro.kernels.spmv import ref
 
 
 def _python_oracle(nbr, bits, n_cols):
@@ -33,19 +34,13 @@ def test_spmv_kernel_sweep(n_rows, max_deg, density):
     np.testing.assert_array_equal(
         np.asarray(ref.spmv_min(jnp.asarray(nbr), f_words, n_cols)), expect
     )
-    np.testing.assert_array_equal(
-        np.asarray(spmv.spmv_min_pallas(jnp.asarray(nbr), f_words, n_cols)), expect
-    )
-    np.testing.assert_array_equal(
-        np.asarray(ops.spmv_min(jnp.asarray(nbr), f_words, n_cols)), expect
-    )
 
 
 @pytest.mark.parametrize("n_rows,max_deg", [(1024, 8), (2048, 16)])
 @pytest.mark.parametrize("density,unreached_frac", [(0.05, 0.5), (0.5, 0.1), (0.5, 1.0)])
 def test_spmv_pull_kernel_sweep(n_rows, max_deg, density, unreached_frac):
     """Pull direction: unreached rows probe the frontier bitmap, finished
-    rows are masked to INF — Pallas kernel vs jnp oracle vs python loop."""
+    rows are masked to INF — XLA formulation vs python loop."""
     n_cols = 4096
     rng = np.random.default_rng(n_rows * max_deg + int(100 * density))
     nbr = rng.integers(0, n_cols, size=(n_rows, max_deg)).astype(np.int32)
@@ -55,17 +50,16 @@ def test_spmv_pull_kernel_sweep(n_rows, max_deg, density, unreached_frac):
     f_words = bpref.pack(jnp.asarray(bits.astype(np.uint32)), 1)
     u_words = bpref.pack(jnp.asarray(unreached.astype(np.uint32)), 1)
     expect = np.where(unreached, _python_oracle(nbr, bits, n_cols), ref.INF)
-    for fn in (ref.spmv_pull_min, pull.spmv_pull_min_pallas, ops.spmv_pull_min):
-        np.testing.assert_array_equal(
-            np.asarray(fn(jnp.asarray(nbr), f_words, u_words, n_cols)), expect
-        )
+    np.testing.assert_array_equal(
+        np.asarray(ref.spmv_pull_min(jnp.asarray(nbr), f_words, u_words, n_cols)),
+        expect,
+    )
 
 
 @pytest.mark.parametrize("density", [0.0, 0.05, 0.5])
 def test_spmv_planes_kernel_sweep(density):
-    """Multi-source expansion: the plane-blocked push/pull kernels equal the
-    single-plane oracle applied per source plane, across ref / Pallas /
-    dispatching ops entry points."""
+    """Multi-source expansion: the plane-batched push/pull entry points equal
+    the python oracle applied per source plane."""
     n_rows, max_deg, n_cols, b = 1024, 16, 4096, 3
     rng = np.random.default_rng(int(density * 100) + 7)
     nbr = rng.integers(0, n_cols, size=(n_rows, max_deg)).astype(np.int32)
@@ -79,22 +73,17 @@ def test_spmv_planes_kernel_sweep(density):
         [bpref.pack(jnp.asarray(p.astype(np.uint32)), 1) for p in unreached]
     )
     expect_push = np.stack([_python_oracle(nbr, p, n_cols) for p in bits])
-    for fn in (ref.spmv_min_planes, spmv.spmv_min_planes_pallas, ops.spmv_min_planes):
-        np.testing.assert_array_equal(
-            np.asarray(fn(jnp.asarray(nbr), f_words, n_cols)), expect_push,
-            err_msg=str(fn),
-        )
+    np.testing.assert_array_equal(
+        np.asarray(ref.spmv_min_planes(jnp.asarray(nbr), f_words, n_cols)),
+        expect_push,
+    )
     expect_pull = np.where(unreached, expect_push, ref.INF)
-    for fn in (
-        ref.spmv_pull_min_planes,
-        pull.spmv_pull_min_planes_pallas,
-        ops.spmv_pull_min_planes,
-    ):
-        np.testing.assert_array_equal(
-            np.asarray(fn(jnp.asarray(nbr), f_words, u_words, n_cols)),
-            expect_pull,
-            err_msg=str(fn),
-        )
+    np.testing.assert_array_equal(
+        np.asarray(
+            ref.spmv_pull_min_planes(jnp.asarray(nbr), f_words, u_words, n_cols)
+        ),
+        expect_pull,
+    )
 
 
 def test_spmv_pull_all_reached_is_inf():
@@ -103,15 +92,15 @@ def test_spmv_pull_all_reached_is_inf():
     nbr = np.zeros((n_rows, 8), np.int32)  # everyone neighbors vertex 0
     f_words = bpref.pack(jnp.ones(n_cols, jnp.uint32), 1)  # full frontier
     u_words = bpref.pack(jnp.zeros(n_rows, jnp.uint32), 1)  # nobody unreached
-    out = np.asarray(pull.spmv_pull_min_pallas(jnp.asarray(nbr), f_words, u_words, n_cols))
+    out = np.asarray(ref.spmv_pull_min(jnp.asarray(nbr), f_words, u_words, n_cols))
     assert (out == ref.INF).all()
 
 
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 1 << 16))
 def test_spmv_matches_segment_min_formulation(seed):
-    """The kernel agrees with the segment_min edge-centric formulation used
-    by core/bfs.py (same semiring, different data structure)."""
+    """The ELL expansion agrees with the segment_min edge-centric formulation
+    used by core/bfs.py (same semiring, different data structure)."""
     import jax
 
     rng = np.random.default_rng(seed)
@@ -125,10 +114,9 @@ def test_spmv_matches_segment_min_formulation(seed):
     cand = np.where(bits[src], src, ref.INF)
     seg = np.full(n_rows, ref.INF, np.int64)
     np.minimum.at(seg, dst, cand)
-    # ELL + kernel (max_deg covers the densest row)
-    deg = np.bincount(dst, minlength=n_rows).max()
-    max_deg = max(int(-(-deg // spmv.DEG_CHUNK) * spmv.DEG_CHUNK), spmv.DEG_CHUNK)
+    # ELL slab (max_deg covers the densest row)
+    max_deg = int(np.bincount(dst, minlength=n_rows).max())
     ell = ref.ell_from_coo(jnp.asarray(src), jnp.asarray(dst), n_rows, n_cols, max_deg)
     f_words = bpref.pack(jnp.asarray(bits.astype(np.uint32)), 1)
-    out = np.asarray(spmv.spmv_min_pallas(ell, f_words, n_cols))
+    out = np.asarray(ref.spmv_min(ell, f_words, n_cols))
     np.testing.assert_array_equal(out, seg)

@@ -76,7 +76,11 @@ def test_oracle_popcount_matches_sum():
         oracle = traversal.DensityOracle(n)
         for density in (0.0, 0.01, 0.5, 1.0):
             bits = jnp.asarray(rng.random(n) < density)
-            assert int(oracle.local_count(bits)) == int(np.asarray(bits).sum())
+            planes = jnp.stack([bits, ~bits])
+            np.testing.assert_array_equal(
+                np.asarray(oracle.plane_counts(planes)),
+                [int(np.asarray(bits).sum()), n - int(np.asarray(bits).sum())],
+            )
 
 
 def test_oracle_hysteresis():
