@@ -22,10 +22,9 @@ def write_bench_comm(
     table: list[dict] | None = None,
     policy_levels: dict | None = None,
     batch: dict | None = None,
-    compute: dict | None = None,
     teps: dict | None = None,
 ) -> None:
-    from benchmarks import bfs_comm, breakdown
+    from benchmarks import bfs_comm
 
     from repro.core import csr as csrmod
 
@@ -45,8 +44,6 @@ def write_bench_comm(
         batch = bfs_comm.run_batch(
             scale=scale, rows=rows, cols=cols, prebuilt=prebuilt
         )
-    if compute is None:
-        compute = breakdown.expansion_breakdown(scale=scale, rows=rows, cols=cols)
     # the multi-source rows ride the same table (batch column + per-source
     # bytes); single-source rows carry batch=1 for uniform consumers
     for r in table:
@@ -81,9 +78,6 @@ def write_bench_comm(
         # multi-source batch section: B=4 planes vs the B=1 replay of the
         # same packed-wire model (shared headers + consensus amortization)
         "batch": batch,
-        # local-expansion compute breakdown: per-level push/pull wall time
-        # per backend on the hub graph (the axis the byte tables can't see)
-        "compute": compute,
         # Graph500 Kernel-2 throughput: harmonic-mean TEPS over the spec's
         # valid-root sample (benchmarks.teps), the trajectory's speed row
         "teps": teps or {},
@@ -118,21 +112,13 @@ def main() -> None:
             f"--xla_force_host_platform_device_count={scaling.DEVICES}",
         )
 
-    from benchmarks import bfs_comm, breakdown, codecs, frontier_stats, teps
+    from benchmarks import bfs_comm, codecs, frontier_stats, teps
 
     bench_table: list[tuple] = []  # shared with write_bench_comm below
-    compute_box: list[dict] = []  # expansion breakdown, shared the same way
     teps_box: list[dict] = []  # harmonic-TEPS row, shared the same way
 
     def teps_suite() -> None:
         teps_box.append(teps.main())
-
-    def breakdown_suite() -> None:
-        breakdown.main_zones()
-        scale, rows, cols = _bench_comm_size(args.full)
-        compute = breakdown.expansion_breakdown(scale=scale, rows=rows, cols=cols)
-        breakdown.print_expansion(compute)
-        compute_box.append(compute)
 
     def bfs_comm_suite() -> None:
         scale, rows, cols = _bench_comm_size(args.full)
@@ -153,7 +139,6 @@ def main() -> None:
         ("codecs (Tables 5.4/5.5)", codecs.main),
         ("frontier_stats (Fig 5.2 / Table 5.3)", frontier_stats.main),
         ("bfs_comm (Tables 7.4/7.5)", bfs_comm_suite),
-        ("breakdown (Fig 7.3 + expansion backends)", breakdown_suite),
         ("teps (§2.6.3)", teps_suite),
     ]
     if args.full and "scaling" not in args.skip:
@@ -180,7 +165,6 @@ def main() -> None:
             write_bench_comm(
                 args.bench_json, args.full, table=table,
                 policy_levels=policy_levels, batch=batch,
-                compute=compute_box[0] if compute_box else None,
                 teps=teps_box[0] if teps_box else None,
             )
         except Exception:  # noqa: BLE001

@@ -92,7 +92,8 @@ def allgather_membership(
     s = ladder.s
     ex = AdaptiveExchange(phase, axis, group_size, ladder, stats)
     if not ladder.specs:  # degenerate ladder: dense bitmap only
-        return ex.dispatch(None, [lambda _: gather_bitmap(ex, bits)])
+        return ex.dispatch(None, [lambda _: gather_bitmap(ex, bits)],
+                           floor=BitmapFormat(s).name)
     count, exc_count = stream_stats(bits)
 
     def sparse_branch(fmt: IdStreamFormat):
@@ -115,7 +116,8 @@ def allgather_membership(
     branches = [sparse_branch(f) for f in ladder.formats()] + [
         lambda _: gather_bitmap(ex, bits)
     ]
-    return ex.dispatch(ladder.bucket_for(count, exc_count), branches)
+    return ex.dispatch(ladder.bucket_for(count, exc_count), branches,
+                       floor=BitmapFormat(s).name)
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +174,8 @@ def allgather_membership_planes(
     assert s == ladder.s, (s, ladder.s)
     ex = AdaptiveExchange(phase, axis, group_size, ladder, stats, planes=b)
     if not ladder.specs:
-        return ex.dispatch(None, [lambda _: gather_bitmap_planes(ex, bits)])
+        return ex.dispatch(None, [lambda _: gather_bitmap_planes(ex, bits)],
+                           floor=BitmapFormat(s).name)
     counts, exc_counts = stream_stats(bits)
     my_bucket = jnp.max(jax.vmap(ladder.bucket_for)(counts, exc_counts))
 
@@ -203,7 +206,7 @@ def allgather_membership_planes(
     branches = [sparse_branch(f) for f in ladder.formats()] + [
         lambda _: gather_bitmap_planes(ex, bits)
     ]
-    return ex.dispatch(my_bucket, branches)
+    return ex.dispatch(my_bucket, branches, floor=BitmapFormat(s).name)
 
 
 # ---------------------------------------------------------------------------
@@ -271,7 +274,8 @@ def alltoall_min_candidates(
     c = group_size
     ex = AdaptiveExchange(phase, axis, group_size, ladder, stats)
     if not ladder.specs:
-        return ex.dispatch(None, [lambda _: alltoall_dense_min(ex, prop)])
+        return ex.dispatch(None, [lambda _: alltoall_dense_min(ex, prop)],
+                           floor=DenseFormat(s).name)
     assert ladder.payload_width > 0, (
         "row-phase ladder must carry the parent payload: build it with "
         "BucketLadder.default(s, floor_words=s, payload_width=...)"
@@ -317,7 +321,7 @@ def alltoall_min_candidates(
     branches = [sparse_branch(f) for f in ladder.formats()] + [
         lambda _: alltoall_dense_min(ex, prop)
     ]
-    return ex.dispatch(my_bucket, branches)
+    return ex.dispatch(my_bucket, branches, floor=DenseFormat(s).name)
 
 
 def alltoall_dense_min_planes(ex: AdaptiveExchange, prop: jax.Array) -> jax.Array:
@@ -373,7 +377,8 @@ def alltoall_min_candidates_planes(
     assert s == ladder.s and c == group_size, (prop.shape, ladder.s, group_size)
     ex = AdaptiveExchange(phase, axis, group_size, ladder, stats, planes=b)
     if not ladder.specs:
-        return ex.dispatch(None, [lambda _: alltoall_dense_min_planes(ex, prop)])
+        return ex.dispatch(None, [lambda _: alltoall_dense_min_planes(ex, prop)],
+                           floor=DenseFormat(s).name)
     assert ladder.payload_width > 0, (
         "row-phase ladder must carry the parent payload: build it with "
         "BucketLadder.default(s, floor_words=s, payload_width=...)"
@@ -436,7 +441,7 @@ def alltoall_min_candidates_planes(
     branches = [sparse_branch(f) for f in ladder.formats()] + [
         lambda _: alltoall_dense_min_planes(ex, prop)
     ]
-    return ex.dispatch(my_bucket, branches)
+    return ex.dispatch(my_bucket, branches, floor=DenseFormat(s).name)
 
 
 def alltoall_bitmap_min_planes(
@@ -571,7 +576,7 @@ def ppermute_min_block(
         return ex.ppermute(block, perm, fmt=floor_fmt.name)
 
     branches = [sparse_branch(f) for f in ladder.formats()] + [floor_branch]
-    return ex.dispatch(my_bucket, branches)
+    return ex.dispatch(my_bucket, branches, floor=floor_fmt.name)
 
 
 def ppermute_membership_block(
@@ -635,7 +640,7 @@ def ppermute_membership_block(
         return jax.vmap(fmt.unpack)(recv.reshape(nb * b, -1)).reshape(nb, b, s)
 
     branches = [sparse_branch(f) for f in ladder.formats()] + [bitmap_branch]
-    return ex.dispatch(my_bucket, branches)
+    return ex.dispatch(my_bucket, branches, floor=BitmapFormat(s).name)
 
 
 # ---------------------------------------------------------------------------

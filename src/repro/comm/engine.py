@@ -34,6 +34,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 
+from repro import obs
 from repro.comm.ladder import BucketLadder
 from repro.comm.stats import CommStats, aval_bytes
 
@@ -84,36 +85,42 @@ class AdaptiveExchange:
         return aval_bytes(out) * (self.group_size - 1) // self.group_size
 
     def all_gather(self, x: jax.Array, *, fmt: str, part: str = "words") -> jax.Array:
-        out = jax.lax.all_gather(x, self.axis, tiled=True)
+        with jax.named_scope(self.phase):
+            out = jax.lax.all_gather(x, self.axis, tiled=True)
         self._rec(fmt, "all-gather", part, out, moved=self._peer_share(out))
         return out
 
     def all_to_all(self, x: jax.Array, *, fmt: str, part: str = "words") -> jax.Array:
-        out = jax.lax.all_to_all(x, self.axis, 0, 0, tiled=True)
+        with jax.named_scope(self.phase):
+            out = jax.lax.all_to_all(x, self.axis, 0, 0, tiled=True)
         self._rec(fmt, "all-to-all", part, out, moved=self._peer_share(out))
         return out
 
     def pmax(self, x: jax.Array, *, fmt: str = CONSENSUS, part: str = "bucket") -> jax.Array:
-        out = jax.lax.pmax(x, self.axis)
+        with jax.named_scope(self.phase):
+            out = jax.lax.pmax(x, self.axis)
         # one consensus serves every plane: never split per plane
         self._rec(fmt, "all-reduce", part, out,
                   moved=2 * self._peer_share(out), per_plane=False)
         return out
 
     def pmin(self, x: jax.Array, *, fmt: str = CONSENSUS, part: str = "bucket") -> jax.Array:
-        out = jax.lax.pmin(x, self.axis)
+        with jax.named_scope(self.phase):
+            out = jax.lax.pmin(x, self.axis)
         # consensus-shaped like pmax (the SSSP window floor rides this)
         self._rec(fmt, "all-reduce", part, out,
                   moved=2 * self._peer_share(out), per_plane=False)
         return out
 
     def psum(self, x: jax.Array, *, fmt: str, part: str = "value") -> jax.Array:
-        out = jax.lax.psum(x, self.axis)
+        with jax.named_scope(self.phase):
+            out = jax.lax.psum(x, self.axis)
         self._rec(fmt, "all-reduce", part, out, moved=2 * self._peer_share(out))
         return out
 
     def ppermute(self, x: jax.Array, perm, *, fmt: str, part: str = "words") -> jax.Array:
-        out = jax.lax.ppermute(x, self.axis, perm)
+        with jax.named_scope(self.phase):
+            out = jax.lax.ppermute(x, self.axis, perm)
         # identity pairs (src == dst) emit full HLO operand bytes but move
         # nothing; ranks outside ``perm`` receive zeros without traffic
         n_moved = sum(1 for src, dst in perm if src != dst)
@@ -127,15 +134,26 @@ class AdaptiveExchange:
         self,
         local_bucket: jax.Array | None,
         branches: Sequence[Callable[[Any], jax.Array]],
+        *,
+        floor: str,
     ) -> jax.Array:
         """Group-consensus branch selection.
 
         ``branches`` is index-aligned with the ladder's sparse formats,
-        dense fallback last.  ``local_bucket`` is this rank's smallest
-        usable bucket (ignored when only one branch exists).
+        dense fallback last; ``floor`` names the fallback's wire format
+        (required: it labels that branch on the trace).
+        ``local_bucket`` is this rank's smallest usable bucket (ignored
+        when only one branch exists).  Each branch runs under the device
+        scope ``{phase}/{format}``, so a trace shows which bucket ran.
         """
         if len(branches) == 1:
             return branches[0](None)
         assert local_bucket is not None
+        names = [f.name for f in self.ladder.formats()] + [floor]
+        assert len(names) == len(branches), (names, len(branches))
         bucket = self.pmax(local_bucket)
-        return jax.lax.switch(bucket, list(branches), operand=None)
+        return jax.lax.switch(
+            bucket,
+            [obs.scoped(f"{self.phase}/{n}")(br) for n, br in zip(names, branches)],
+            operand=None,
+        )

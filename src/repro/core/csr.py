@@ -19,6 +19,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.graphgen.builder import (
     CSRGraph,
     _round_up,
@@ -192,6 +193,7 @@ def ell_blocked(bg: BlockedGraph, deg_multiple: int = 8) -> ELLBlocks:
     return ELLBlocks(part=part, nbr=nbr, split_k=np.full((r, c), k, np.int32))
 
 
+@obs.span("hybrid_blocked")
 def hybrid_blocked(
     bg: BlockedGraph,
     waste_budget: float = 0.5,

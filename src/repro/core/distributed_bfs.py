@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.comm import AdaptiveExchange, CommStats, ThresholdPolicy
 from repro.comm import collectives as comm_cc
 from repro.comm import registry as wire_registry
@@ -152,26 +153,28 @@ def _bfs_local(
     # dead collectives reach the HLO or the CommStats ledger.  Every builder
     # gets the plane count: B frontier planes share each exchange's header
     # and bucket consensus.
+    # Each exchange runs under the device scope of its CommStats phase,
+    # its compaction and packing included, so trace and ledger share names.
     plan = wire_registry.wire_plan(cfg.mode)
-    column_gather = plan.build_column(
+    column_gather = obs.scoped(f"{p}/column")(plan.build_column(
         s, cfg.row_axes, r, b=b, policy=threshold, stats=stats,
         phase=f"{p}/column",
-    )
+    ))
     row_exchange = row_exchange_bu = unreached_gather = None
     if policy.uses_top_down:
-        row_exchange = plan.build_row(
+        row_exchange = obs.scoped(f"{p}/row")(plan.build_row(
             s, cfg.col_axis, c, n_c, p_width, b=b,
             policy=threshold, stats=stats, phase=f"{p}/row", alg=alg,
-        )
+        ))
     if policy.uses_bottom_up:
-        row_exchange_bu = plan.build_row_bu(
+        row_exchange_bu = obs.scoped(f"{p}/row-pull")(plan.build_row_bu(
             s, cfg.col_axis, c, n_c, p_width, b=b,
             policy=threshold, stats=stats, phase=f"{p}/row-pull", alg=alg,
-        )
-        unreached_gather = plan.build_unreached(
+        ))
+        unreached_gather = obs.scoped(f"{p}/unreached")(plan.build_unreached(
             s, cfg.col_axis, c, b=b,
             policy=threshold, stats=stats, phase=f"{p}/unreached",
-        )
+        ))
     # non-adaptive exchanges report through the same engine facade; the
     # termination psum carries all B plane counts in one all-reduce (plus,
     # for adaptive policies, a float32 m_f/m_u companion — same total words
@@ -199,16 +202,21 @@ def _bfs_local(
         # actual consumption: a recorded-but-dead psum would be DCE'd from
         # the HLO and break the ledger reconciliation.
         ex_degree = AdaptiveExchange(f"{p}/degree", cfg.col_axis, c, None, stats)
-        deg_slice = traversal.degree_vector(dst_l, n_r)
-        deg_row = ex_degree.psum(deg_slice, fmt="degree")
-        deg_own = jax.lax.dynamic_slice(deg_row, (j * s,), (s,))
+        with jax.named_scope(f"{p}/degree"):
+            with jax.named_scope(f"{p}/row-offsets"):
+                deg_slice = traversal.degree_vector(dst_l, n_r)
+            deg_row = ex_degree.psum(deg_slice, fmt="degree")
+            deg_own = jax.lax.dynamic_slice(deg_row, (j * s,), (s,))
 
     # local expansion through the backend: the block containers were built
     # at partition time and sharded next to the COO arrays; expansion is
     # compute-local, so backend choice cannot touch the CommStats ledger
-    # or the collectives above
+    # or the collectives above.  The row-offset searches (the COO edges'
+    # here, the whole block's in the degree vector) run once per call,
+    # outside the level loop, under their own scope.
     backend = expand_mod.resolve(cfg.expand)
-    block = backend.local_block(src_l, dst_l, extra, n_r, n_c)
+    with jax.named_scope(f"{p}/row-offsets"):
+        block = backend.local_block(src_l, dst_l, extra, n_r, n_c)
 
     ctx = traversal.DistLevelCtx(
         expand=backend,
@@ -236,15 +244,21 @@ def _bfs_local(
         f_col = column_gather(bits_t)
         x_col = None
         if alg.needs_values:
-            x_own = alg.source_values(carry.value, deg_own)
-            x_t = ex_transpose.ppermute(x_own, perm, fmt="values")
-            x_col = comm_cc.gather_values_planes(ex_values, x_t)
+            with jax.named_scope(f"{p}/values"):
+                x_own = alg.source_values(carry.value, deg_own)
+                x_t = ex_transpose.ppermute(x_own, perm, fmt="values")
+                x_col = comm_cc.gather_values_planes(ex_values, x_t)
         # 3+4. policy-directed local expansion + row exchange (per-plane
         # direction; planes with empty frontiers ride as masked planes)
-        reduced = policy.expand_dist(
-            ctx, carry.value, f_col, carry.use_bu, carry.counts > 0,
-            x_col=x_col,
-        )
+        with jax.named_scope(f"{p}/expand"):
+            reduced = policy.expand_dist(
+                ctx, carry.value, f_col, carry.use_bu, carry.counts > 0,
+                x_col=x_col,
+            )
+        return update(carry, reduced)
+
+    @obs.scoped(f"{p}/update")
+    def update(carry: _Carry, reduced) -> _Carry:
         # 5. fold candidates into the owned state through the algebra; the
         # psum-ed improvement counts feed the termination test and (for
         # direction_opt) each plane's direction
@@ -391,15 +405,17 @@ def shard_blocked(mesh: Mesh, bg: BlockedGraph, cfg: DistBFSConfig | None = None
         row_axes=tuple(mesh.axis_names[:-1]), col_axis=mesh.axis_names[-1]
     )
     sizes = tuple(mesh.shape[a] for a in cfg.all_axes)
-    spec = P(*cfg.row_axes, cfg.col_axis, None)
-    sharding = NamedSharding(mesh, spec)
-    src = jax.device_put(bg.src_local.reshape(sizes + (-1,)), sharding)
-    dst = jax.device_put(bg.dst_local.reshape(sizes + (-1,)), sharding)
     backend = expand_mod.resolve(cfg.expand)
-    extra = []
-    for a, nd in zip(backend.block_arrays(bg), backend.extra_ndims):
-        tail = a.shape[2:]
-        assert len(tail) == nd, (a.shape, nd)
-        esharding = NamedSharding(mesh, P(*cfg.row_axes, cfg.col_axis, *(None,) * nd))
-        extra.append(jax.device_put(a.reshape(sizes + tail), esharding))
+    containers = backend.block_arrays(bg)  # host build, e.g. hybrid_blocked
+    with obs.span("device_put"):
+        sharding = NamedSharding(mesh, P(*cfg.row_axes, cfg.col_axis, None))
+        src = jax.device_put(bg.src_local.reshape(sizes + (-1,)), sharding)
+        dst = jax.device_put(bg.dst_local.reshape(sizes + (-1,)), sharding)
+        extra = []
+        for a, nd in zip(containers, backend.extra_ndims):
+            tail = a.shape[2:]
+            assert len(tail) == nd, (a.shape, nd)
+            esharding = NamedSharding(
+                mesh, P(*cfg.row_axes, cfg.col_axis, *(None,) * nd))
+            extra.append(jax.device_put(a.reshape(sizes + tail), esharding))
     return (src, dst, *extra)

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.comm import registry as wire_registry
 from repro.comm.formats import INF
 from repro.core import csr as csrmod
@@ -144,6 +145,7 @@ def _first_hit(src, row_ptr, hit):
     return jnp.where(found, jnp.take(src, first, mode="clip"), INF)
 
 
+@obs.scoped("residue")
 def _coo_push(src, row_ptr, n_cols: int, f):
     """(B, n_cols) frontier planes -> (B, n_rows) min frontier source per
     destination (column-LOCAL ids) over destination-major edges."""
@@ -155,6 +157,7 @@ def _coo_push(src, row_ptr, n_cols: int, f):
     return jax.lax.map(one, f)
 
 
+@obs.scoped("residue")
 def _coo_pull(src, row_ptr, n_cols: int, f, unreached):
     """Pull over COO edges: the frontier is probed through its *packed*
     bitmap (the representation switch the pull direction is about), and
@@ -169,11 +172,13 @@ def _coo_pull(src, row_ptr, n_cols: int, f, unreached):
     return jax.lax.map(lambda a: one(*a), (words, unreached))
 
 
+@obs.scoped("slab")
 def _ell_push(nbr, n_cols: int, f):
     """ELL slab push through the plane-batched SpMV."""
     return spmv_ref.spmv_min_planes(nbr, _pack_planes(f), _chunk_pad(n_cols))
 
 
+@obs.scoped("slab")
 def _ell_pull(nbr, n_cols: int, f, unreached):
     """ELL pull: resident frontier + unreached bitmaps, finished rows INF."""
     return spmv_ref.spmv_pull_min_planes(
@@ -181,6 +186,7 @@ def _ell_pull(nbr, n_cols: int, f, unreached):
     )
 
 
+@obs.scoped("residue")
 def _coo_push_value(src, dst, n_rows, n_cols, f, x, alg, row_base, col_base):
     """Value-algebra push over COO edges: each active edge proposes
     ``alg.edge_message`` of its source's value, reduced per destination
@@ -196,6 +202,7 @@ def _coo_push_value(src, dst, n_rows, n_cols, f, x, alg, row_base, col_base):
     return jax.lax.map(lambda a: one(*a), (f, x))
 
 
+@obs.scoped("residue")
 def _coo_pull_value(src, dst, n_rows, n_cols, f, unreached, x, alg,
                     row_base, col_base):
     """Value-algebra pull over COO edges: the frontier is probed through
@@ -215,6 +222,7 @@ def _coo_pull_value(src, dst, n_rows, n_cols, f, unreached, x, alg,
     return jax.lax.map(lambda a: one(*a), (words, unreached, x))
 
 
+@obs.scoped("slab")
 def _ell_push_value(nbr, n_cols: int, f, x, alg, row_base, col_base):
     """ELL value push through the op x reduce gspmm."""
     return spmv_ref.gspmm_planes(
@@ -223,6 +231,7 @@ def _ell_push_value(nbr, n_cols: int, f, x, alg, row_base, col_base):
     )
 
 
+@obs.scoped("slab")
 def _ell_pull_value(nbr, n_cols: int, f, unreached, x, alg, row_base, col_base):
     """ELL value pull: masked destinations collapse to the empty sentinel."""
     return spmv_ref.gspmm_planes(

@@ -186,6 +186,12 @@ class DistLevelCtx(NamedTuple):
     row_base: jax.Array | int = 0  # global id of this rank's first row (i*n_r)
 
 
+def _phase(ctx: DistLevelCtx, name: str) -> str:
+    """Device scope of one gated expansion pass, prefixed like the
+    algebra's CommStats phases (``bfs/expand/push``)."""
+    return f"{ctx.algebra.name if ctx.algebra is not None else 'bfs'}/{name}"
+
+
 class TraversalPolicy:
     """One frontier-expansion direction, or a per-level switch over them.
 
@@ -264,7 +270,8 @@ class TopDownPolicy(TraversalPolicy):
 
     def expand_dist(self, ctx, value, f_col, use_bu, active,
                     x_col=None, plane_mask=None):
-        return ctx.row_exchange(self._propose(ctx, f_col, x_col))
+        with jax.named_scope(_phase(ctx, "expand/push")):
+            return ctx.row_exchange(self._propose(ctx, f_col, x_col))
 
 
 class BottomUpPolicy(TraversalPolicy):
@@ -288,27 +295,28 @@ class BottomUpPolicy(TraversalPolicy):
 
     def expand_dist(self, ctx, value, f_col, use_bu, active,
                     x_col=None, plane_mask=None):
-        alg = ctx.algebra
-        # pull-mask membership of the whole row slice, gathered as bitmap
-        # planes over the grid row — this replaces the id-stream ALLTOALLV.
-        # Exhausted planes are masked out: their permanent unreached set
-        # (often most of the graph) must not escalate the bucket consensus
-        # the surviving planes' gather pays for, and the host replay prices
-        # inactive planes as empty.
-        mask = (value < 0) if alg is None else alg.pull_mask(value)
-        pm = active if plane_mask is None else (plane_mask & active)
-        unreached = ctx.unreached_gather(mask & pm[:, None])  # (B, n_r) bool
-        if alg is None or alg.payload_is_id:
-            # candidates stay column-LOCAL so the wire payload bit-packs at
-            # the static column-width class; the receiver globalizes per
-            # sender
-            local = ctx.expand.pull_planes(ctx.block, f_col, unreached)
-        else:
-            local = ctx.expand.pull_value_planes(
-                ctx.block, f_col, unreached, x_col, alg,
-                row_base=ctx.row_base, col_base=ctx.col_index * ctx.n_c,
-            )
-        return ctx.row_exchange_bu(local.reshape(-1, ctx.c, ctx.s))
+        with jax.named_scope(_phase(ctx, "expand/pull")):
+            alg = ctx.algebra
+            # pull-mask membership of the whole row slice, gathered as bitmap
+            # planes over the grid row — this replaces the id-stream ALLTOALLV.
+            # Exhausted planes are masked out: their permanent unreached set
+            # (often most of the graph) must not escalate the bucket consensus
+            # the surviving planes' gather pays for, and the host replay prices
+            # inactive planes as empty.
+            mask = (value < 0) if alg is None else alg.pull_mask(value)
+            pm = active if plane_mask is None else (plane_mask & active)
+            unreached = ctx.unreached_gather(mask & pm[:, None])  # (B, n_r) bool
+            if alg is None or alg.payload_is_id:
+                # candidates stay column-LOCAL so the wire payload bit-packs at
+                # the static column-width class; the receiver globalizes per
+                # sender
+                local = ctx.expand.pull_planes(ctx.block, f_col, unreached)
+            else:
+                local = ctx.expand.pull_value_planes(
+                    ctx.block, f_col, unreached, x_col, alg,
+                    row_base=ctx.row_base, col_base=ctx.col_index * ctx.n_c,
+                )
+            return ctx.row_exchange_bu(local.reshape(-1, ctx.c, ctx.s))
 
 
 class DirectionOptPolicy(TraversalPolicy):

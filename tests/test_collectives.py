@@ -282,7 +282,7 @@ def test_adaptive_exchange_bucket_choice_monotone():
             functools.partial(lambda i, _: jnp.int32(i), i)
             for i in range(ladder.n_branches)
         ]
-        return ex.dispatch(ladder.bucket_for(count, exc), branches)
+        return ex.dispatch(ladder.bucket_for(count, exc), branches, floor="bitmap")
 
     f = jax.jit(jax.shard_map(which_branch, mesh=mesh, in_specs=P(), out_specs=P()))
     rng = np.random.default_rng(0)
