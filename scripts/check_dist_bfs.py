@@ -33,8 +33,8 @@ def main() -> None:
     for mode, policy, alpha in combos:
         cfg = dbfs.DistBFSConfig(mode=mode, policy=policy, alpha=alpha)
         fn = dbfs.build_bfs(mesh, bg, cfg)
-        src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-        parent, level, depth = fn(src_l, dst_l, jnp.int32(0))
+        blocks = dbfs.shard_blocked(mesh, bg, cfg)
+        parent, level, depth = fn(*blocks, jnp.int32(0))
         parent = np.asarray(parent)[: g.n]
         level = np.asarray(level)[: g.n]
         assert np.array_equal(level, ref_levels), (

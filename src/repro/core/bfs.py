@@ -180,14 +180,15 @@ def _bfs_batched(src, dst, roots, n, policy, max_levels, expand, extra,
     alg = algebra_mod.resolve(algebra)
     backend = expand_mod.resolve(expand)
     src, dst = expand_mod.dst_major(src, dst)
-    block = backend.local_block(src, dst, extra, n, n)
+    row_ptr = expand_mod.row_offsets(dst, n)
+    block = backend.local_block(src, dst, row_ptr, extra, n, n)
     oracle = traversal.DensityOracle(n)
     # the degree vector is computed once before the level loop, and only
     # when something consumes it: the anticipatory direction oracle or the
     # plus-times algebra's x = v/deg source messages
     deg = None
     if (pol.uses_top_down and pol.uses_bottom_up) or alg.needs_deg:
-        deg = traversal.degree_vector(dst, n)
+        deg = traversal.degree_vector(row_ptr)
     out = jax.lax.while_loop(
         lambda s: s.active & (s.depth < max_levels),
         lambda s: traversal.level_once(src, dst, n, pol, oracle, s, deg=deg,
@@ -253,11 +254,12 @@ def _bfs_levels_batched(src, dst, roots, n, max_levels, policy, expand, extra):
     alg = algebra_mod.resolve("bfs")
     backend = expand_mod.resolve(expand)
     src, dst = expand_mod.dst_major(src, dst)
-    block = backend.local_block(src, dst, extra, n, n)
+    row_ptr = expand_mod.row_offsets(dst, n)
+    block = backend.local_block(src, dst, row_ptr, extra, n, n)
     oracle = traversal.DensityOracle(n)
     deg = None
     if pol.uses_top_down and pol.uses_bottom_up:
-        deg = traversal.degree_vector(dst, n)
+        deg = traversal.degree_vector(row_ptr)
 
     def body(state, _):
         state = jax.lax.cond(

@@ -23,7 +23,7 @@ from repro import obs
 from repro.graphgen.builder import (
     CSRGraph,
     _round_up,
-    edge_degrees,
+    degree_offsets,
     ell_from_edges,
     select_split_k,
 )
@@ -83,18 +83,25 @@ class BlockedGraph:
     ``src_local`` indexes into the column slice [0, n_c); ``dst_local`` into
     the row slice [0, n_r).  Each block is destination-major (sorted by
     destination, then source), and padding edges at the end use (n_c, n_r)
-    sentinels.
+    sentinels.  ``row_ptr[i, j, v]`` is the first edge of row ``v`` in
+    block ``A_ij``; the sentinel tail sorts past the last row, so
+    ``row_ptr[i, j, n_r]`` is the block's valid edge count.
     """
 
     part: Partition2D
     src_local: np.ndarray  # (R, C, e_cap) int32
     dst_local: np.ndarray  # (R, C, e_cap) int32
+    row_ptr: np.ndarray  # (R, C, n_r + 1) int32 row offsets per block
     e_counts: np.ndarray  # (R, C) int64 true edge counts per block
     m_input: int
 
     @property
     def e_cap(self) -> int:
         return int(self.src_local.shape[-1])
+
+    def degrees(self) -> np.ndarray:
+        """(R, C, n_r) per-destination degree of every block."""
+        return np.diff(self.row_ptr, axis=-1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,13 +140,15 @@ class HybridBlocks:
     of one static capacity, so every array shards like the flat edge
     blocks.  ``min(slab expansion, residue expansion)`` is bit-identical to
     the flat segment_min because each row's edges live in exactly one of
-    the two structures.
+    the two structures.  ``res_row_ptr`` holds the residue's row offsets:
+    rows on the slab are empty there.
     """
 
     part: Partition2D
     nbr: np.ndarray  # (R, C, n_r, k) int32, sentinel n_c
     res_src: np.ndarray  # (R, C, r_cap) int32, sentinel n_c
     res_dst: np.ndarray  # (R, C, r_cap) int32, sentinel n_r
+    res_row_ptr: np.ndarray  # (R, C, n_r + 1) int32 residue row offsets
     split_k: np.ndarray  # (R, C) int32 per-block degree split
 
     @property
@@ -157,21 +166,12 @@ class HybridBlocks:
         return pad / slots
 
 
-def _block_degrees(src: np.ndarray, dst: np.ndarray, part: Partition2D) -> np.ndarray:
-    return edge_degrees(src, dst, part.n_r, part.n_c)
-
-
 def ell_slab_width(bg: BlockedGraph, deg_multiple: int = 8) -> int:
     """The slab width :func:`ell_blocked` will use: the max row degree over
     ALL blocks, rounded to a multiple of 8.  The single place the
     pure-ELL affordability estimate lives, so memory guards (the benchmark's
     slab budget) cannot drift from what the container actually allocates."""
-    part = bg.part
-    max_deg = max(
-        int(_block_degrees(bg.src_local[i, j], bg.dst_local[i, j], part).max(initial=0))
-        for i in range(part.rows)
-        for j in range(part.cols)
-    )
+    max_deg = int(bg.degrees().max(initial=0))
     return _round_up(max(max_deg, 1), deg_multiple)
 
 
@@ -207,15 +207,16 @@ def hybrid_blocked(
     (:func:`repro.graphgen.builder.select_split_k`, keeping ELL padding
     waste under ``waste_budget``) unless a fixed ``split_k`` is forced; the
     slab width and residue capacity are the max over blocks so shapes stay
-    static for ``shard_map``.
+    static for ``shard_map``.  The residue's row offsets come from the
+    blocks' degree histograms, with the rows on the slab left empty.
     """
     part = bg.part
     r, c = part.rows, part.cols
+    deg = bg.degrees()
     ks = np.empty((r, c), np.int32)
     for i in range(r):
         for j in range(c):
-            deg = _block_degrees(bg.src_local[i, j], bg.dst_local[i, j], part)
-            ks[i, j] = split_k or select_split_k(deg, waste_budget, deg_multiple)
+            ks[i, j] = split_k or select_split_k(deg[i, j], waste_budget, deg_multiple)
     width = _round_up(int(ks.max(initial=1)), deg_multiple)
     slabs = np.empty((r, c, part.n_r, width), np.int32)
     residues = []
@@ -234,8 +235,10 @@ def hybrid_blocked(
         i, j = divmod(b, c)
         res_src[i, j, : res_s.size] = res_s
         res_dst[i, j, : res_d.size] = res_d
+    res_deg = np.where(deg > ks[:, :, None], deg, 0)
     return HybridBlocks(
-        part=part, nbr=slabs, res_src=res_src, res_dst=res_dst, split_k=ks
+        part=part, nbr=slabs, res_src=res_src, res_dst=res_dst,
+        res_row_ptr=degree_offsets(res_deg), split_k=ks,
     )
 
 
@@ -260,6 +263,8 @@ def partition_2d(
 
     ``chunk_multiple`` keeps the owned-chunk width s a multiple of the
     bit-packing chunk (1024) so compressed exchanges stay lane-aligned.
+    Each block's row offsets are built here, from one degree histogram
+    per block, so the device program never searches for them.
     """
     n, _ = padded_geometry(g.n, rows, cols, chunk_multiple)
     part = Partition2D(n=n, n_orig=g.n, rows=rows, cols=cols)
@@ -279,6 +284,7 @@ def partition_2d(
 
     src_l = np.full((rows * cols, e_cap), part.n_c, dtype=np.int32)
     dst_l = np.full((rows * cols, e_cap), part.n_r, dtype=np.int32)
+    deg = np.zeros((rows * cols, part.n_r), dtype=np.int64)
     starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
     for b in range(rows * cols):
         s0, cnt = starts[b], counts[b]
@@ -287,11 +293,13 @@ def partition_2d(
         i, j = divmod(b, cols)
         np.subtract(src[s0 : s0 + cnt], j * part.n_c, out=src_l[b, :cnt])
         np.subtract(dst[s0 : s0 + cnt], i * part.n_r, out=dst_l[b, :cnt])
+        deg[b] = np.bincount(dst_l[b, :cnt], minlength=part.n_r)
 
     return BlockedGraph(
         part=part,
         src_local=src_l.reshape(rows, cols, e_cap),
         dst_local=dst_l.reshape(rows, cols, e_cap),
+        row_ptr=degree_offsets(deg).reshape(rows, cols, part.n_r + 1),
         e_counts=counts.reshape(rows, cols),
         m_input=g.m_input,
     )

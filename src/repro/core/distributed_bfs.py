@@ -106,6 +106,7 @@ class _Carry(NamedTuple):
 def _bfs_local(
     src_l,
     dst_l,
+    row_ptr,
     extra,
     roots,
     *,
@@ -115,13 +116,15 @@ def _bfs_local(
     threshold: ThresholdPolicy | None = None,
 ):
     """Per-rank body (inside shard_map). src_l/dst_l: (1,..,1,e_cap);
+    ``row_ptr``: (1,..,1,n_r+1) the block's row offsets, built on the host;
     ``extra``: the expansion backend's per-block containers (ELL slab /
-    hybrid residue), same leading singleton grid axes; ``roots``: (B,)
-    replicated source vertices — the batch dimension B is a first-class
-    axis here, carried as (B, s) planes through every phase."""
+    hybrid residue and its offsets), same leading singleton grid axes;
+    ``roots``: (B,) replicated source vertices — the batch dimension B is
+    a first-class axis here, carried as (B, s) planes through every phase."""
     grid_nd = len(cfg.row_axes) + 1
     src_l = src_l.reshape(-1)
     dst_l = dst_l.reshape(-1)
+    row_ptr = row_ptr.reshape(-1)
     extra = tuple(a.reshape(a.shape[grid_nd:]) for a in extra)
     b = roots.shape[0]
     r, c, s = part.rows, part.cols, part.chunk
@@ -204,19 +207,19 @@ def _bfs_local(
         ex_degree = AdaptiveExchange(f"{p}/degree", cfg.col_axis, c, None, stats)
         with jax.named_scope(f"{p}/degree"):
             with jax.named_scope(f"{p}/row-offsets"):
-                deg_slice = traversal.degree_vector(dst_l, n_r)
+                deg_slice = traversal.degree_vector(row_ptr)
             deg_row = ex_degree.psum(deg_slice, fmt="degree")
             deg_own = jax.lax.dynamic_slice(deg_row, (j * s,), (s,))
 
-    # local expansion through the backend: the block containers were built
-    # at partition time and sharded next to the COO arrays; expansion is
-    # compute-local, so backend choice cannot touch the CommStats ledger
-    # or the collectives above.  The row-offset searches (the COO edges'
-    # here, the whole block's in the degree vector) run once per call,
-    # outside the level loop, under their own scope.
+    # local expansion through the backend: the block containers and every
+    # row-offset array were built at partition time and sharded next to
+    # the COO arrays; expansion is compute-local, so backend choice cannot
+    # touch the CommStats ledger or the collectives above.  What remains
+    # of the row offsets per call (reading the placed arrays here, their
+    # differences in the degree vector) runs under its own scope.
     backend = expand_mod.resolve(cfg.expand)
     with jax.named_scope(f"{p}/row-offsets"):
-        block = backend.local_block(src_l, dst_l, extra, n_r, n_c)
+        block = backend.local_block(src_l, dst_l, row_ptr, extra, n_r, n_c)
 
     ctx = traversal.DistLevelCtx(
         expand=backend,
@@ -315,9 +318,12 @@ def build_bfs(
     -> (parent, level, n_levels) with outputs sharded over all axes.
 
     ``blocks`` are the sharded arrays :func:`shard_blocked` produced for
-    ``cfg.expand`` — ``(src_l, dst_l)`` for the COO backend (the legacy
-    signature), plus the backend's block containers (ELL slab / hybrid
-    residue) otherwise; call as ``fn(*shard_blocked(...), root)``.
+    ``cfg.expand`` — ``(src_l, dst_l, row_ptr)`` for every backend (the
+    blocks' edges and their row offsets, built on the host with the
+    partition), then the backend's block containers (ELL slab / hybrid
+    residue and its offsets); call as ``fn(*shard_blocked(...), root)``.
+    The program reads every row offset it uses from these arrays and
+    searches for none.
 
     ``root`` may be a scalar source (legacy ``(n,)`` outputs) or a ``(B,)``
     batch of distinct sources — batched calls return ``(B, n)`` parent and
@@ -361,7 +367,7 @@ def build_bfs(
     mapped = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(blk_spec, blk_spec, extra_specs, P()),
+        in_specs=(blk_spec, blk_spec, blk_spec, extra_specs, P()),
         out_specs=(out_spec, out_spec, P()),
         # the direction/plane-mask conds mix replicated consensus values with
         # per-rank planes; like the other shard_map callers, skip the
@@ -369,7 +375,7 @@ def build_bfs(
         check_vma=False,
     )
     jitted = jax.jit(mapped)
-    n_blocks = 2 + len(backend.extra_ndims)
+    n_blocks = 3 + len(backend.extra_ndims)
 
     def jit_args(args):
         if len(args) != n_blocks + 1:
@@ -380,7 +386,7 @@ def build_bfs(
             )
         *blocks, root = args
         roots = bfs.validate_roots(root, part.n_orig)
-        return (blocks[0], blocks[1], tuple(blocks[2:]),
+        return (*blocks[:3], tuple(blocks[3:]),
                 jnp.atleast_1d(roots)), roots.ndim == 0
 
     def run(*args):
@@ -397,25 +403,27 @@ def build_bfs(
 
 
 def shard_blocked(mesh: Mesh, bg: BlockedGraph, cfg: DistBFSConfig | None = None):
-    """Place the blocked edge arrays — and the expansion backend's block
-    containers (ELL slab / hybrid residue for ``cfg.expand``) — on the
-    mesh.  Returns ``(src, dst, *backend arrays)``; the COO default keeps
-    the legacy two-tuple."""
+    """Place the blocked edge arrays, their row offsets, and the expansion
+    backend's block containers (ELL slab / hybrid residue and its offsets
+    for ``cfg.expand``) on the mesh, each with the leading ``(R, C)`` grid
+    axes sharded.  Returns ``(src, dst, row_ptr, *backend arrays)``.
+    Every row-offset array comes from the host (``bg.row_ptr``,
+    ``HybridBlocks.res_row_ptr``), built from the blocks' degree
+    histograms at set-up."""
     cfg = cfg or DistBFSConfig(
         row_axes=tuple(mesh.axis_names[:-1]), col_axis=mesh.axis_names[-1]
     )
     sizes = tuple(mesh.shape[a] for a in cfg.all_axes)
     backend = expand_mod.resolve(cfg.expand)
     containers = backend.block_arrays(bg)  # host build, e.g. hybrid_blocked
+    arrays = (bg.src_local, bg.dst_local, bg.row_ptr, *containers)
+    ndims = (1, 1, 1, *backend.extra_ndims)
+    placed = []
     with obs.span("device_put"):
-        sharding = NamedSharding(mesh, P(*cfg.row_axes, cfg.col_axis, None))
-        src = jax.device_put(bg.src_local.reshape(sizes + (-1,)), sharding)
-        dst = jax.device_put(bg.dst_local.reshape(sizes + (-1,)), sharding)
-        extra = []
-        for a, nd in zip(containers, backend.extra_ndims):
+        for a, nd in zip(arrays, ndims, strict=True):
             tail = a.shape[2:]
             assert len(tail) == nd, (a.shape, nd)
-            esharding = NamedSharding(
+            sharding = NamedSharding(
                 mesh, P(*cfg.row_axes, cfg.col_axis, *(None,) * nd))
-            extra.append(jax.device_put(a.reshape(sizes + tail), esharding))
-    return (src, dst, *extra)
+            placed.append(jax.device_put(a.reshape(sizes + tail), sharding))
+    return tuple(placed)

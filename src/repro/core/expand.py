@@ -87,7 +87,11 @@ class LocalBlock(NamedTuple):
     side, ``n_rows`` on the destination side.  COO edges are
     destination-major (sorted by destination, then source; the partition
     and the single-device driver lay them out so), and ``row_ptr`` holds
-    each destination row's first edge.
+    each destination row's first edge in them.  It is never searched for
+    on the distributed path: the whole block's offsets are built with the
+    partition and the residue's with the hybrid containers, on the host at
+    set-up, and placed next to the edge blocks.  The single-device driver
+    sorts its edges on the device and searches their offsets there.
     """
 
     src: jax.Array  # (e,) column-local sources
@@ -113,7 +117,9 @@ _POS_MAX = int(jnp.iinfo(jnp.int32).max)
 def row_offsets(dst, n_rows: int) -> jax.Array:
     """(n_rows + 1,) start of each destination row in a destination-major
     edge array (sentinel destinations ``>= n_rows`` sort past the last):
-    a binary search per row, all rows at once."""
+    a binary search per row, all rows at once.  For the single-device
+    driver, whose edges are sorted on the device; the distributed driver
+    reads offsets built at set-up (:func:`repro.graphgen.builder.degree_offsets`)."""
     e = min(dst.shape[0], _POS_MAX)
     rows = jax.lax.iota(dst.dtype, n_rows + 1)
 
@@ -248,7 +254,8 @@ class ExpansionBackend:
     :class:`~repro.core.csr.BlockedGraph`; each distributed array leads
     with the ``(R, C)`` grid axes so the driver can shard it like the edge
     blocks.  Device side, ``local_block`` assembles the per-rank
-    :class:`LocalBlock` and ``push_planes``/``pull_planes`` expand all B
+    :class:`LocalBlock` from the edges, their row offsets ``row_ptr`` and
+    the extra arrays, and ``push_planes``/``pull_planes`` expand all B
     frontier planes at once, returning ``(B, n_rows)`` column-local
     min-candidate ids (INF where none) — the traversal policy owns
     globalization and the wire.
@@ -266,7 +273,8 @@ class ExpansionBackend:
     def block_arrays(self, bg: csrmod.BlockedGraph) -> tuple[np.ndarray, ...]:
         return ()
 
-    def local_block(self, src, dst, extra, n_rows: int, n_cols: int) -> LocalBlock:
+    def local_block(self, src, dst, row_ptr, extra, n_rows: int,
+                    n_cols: int) -> LocalBlock:
         raise NotImplementedError
 
     def push_planes(self, blk: LocalBlock, f):
@@ -295,10 +303,10 @@ class ExpansionBackend:
 class CooExpansion(ExpansionBackend):
     name = "coo"
 
-    def local_block(self, src, dst, extra, n_rows, n_cols):
+    def local_block(self, src, dst, row_ptr, extra, n_rows, n_cols):
         assert extra == (), extra
         return LocalBlock(src=src, dst=dst, nbr=None, n_rows=n_rows,
-                          n_cols=n_cols, row_ptr=row_offsets(dst, n_rows))
+                          n_cols=n_cols, row_ptr=row_ptr)
 
     def push_planes(self, blk, f):
         return _coo_push(blk.src, blk.row_ptr, blk.n_cols, f)
@@ -334,7 +342,7 @@ class EllExpansion(ExpansionBackend):
     def _blocks(self, bg):
         return _graph_cached(self, bg, csrmod.ell_blocked)
 
-    def local_block(self, src, dst, extra, n_rows, n_cols):
+    def local_block(self, src, dst, row_ptr, extra, n_rows, n_cols):
         (nbr,) = extra
         return LocalBlock(src=src, dst=dst, nbr=nbr, n_rows=n_rows, n_cols=n_cols)
 
@@ -369,18 +377,19 @@ class HybridExpansion(ExpansionBackend):
     """Degree-split COO/ELL: low-degree rows on the slab, hubs in COO."""
 
     name = "hybrid"
-    extra_ndims = (2, 1, 1)  # (n_r, k) slab + (r_cap,) residue src/dst
+    #: (n_r, k) slab, (r_cap,) residue src/dst, (n_r + 1,) residue offsets
+    extra_ndims = (2, 1, 1, 1)
 
     def __init__(self, waste_budget: float = 0.5, split_k: int | None = None):
         self.waste_budget = waste_budget
         self.split_k = split_k
 
     def graph_arrays(self, src, dst, n):
-        nbr, res_s, res_d, _ = builder.hybrid_graph_arrays(
+        nbr, res_s, res_d, res_ptr, _ = builder.hybrid_graph_arrays(
             np.asarray(src), np.asarray(dst), n,
             waste_budget=self.waste_budget, split_k=self.split_k,
         )
-        return (nbr, res_s, res_d)
+        return (nbr, res_s, res_d, res_ptr)
 
     def _blocks(self, bg):
         return _graph_cached(
@@ -392,13 +401,13 @@ class HybridExpansion(ExpansionBackend):
 
     def block_arrays(self, bg):
         h = self._blocks(bg)
-        return (h.nbr, h.res_src, h.res_dst)
+        return (h.nbr, h.res_src, h.res_dst, h.res_row_ptr)
 
-    def local_block(self, src, dst, extra, n_rows, n_cols):
-        nbr, res_src, res_dst = extra
+    def local_block(self, src, dst, row_ptr, extra, n_rows, n_cols):
+        nbr, res_src, res_dst, res_ptr = extra
         return LocalBlock(
             src=res_src, dst=res_dst, nbr=nbr, n_rows=n_rows, n_cols=n_cols,
-            row_ptr=row_offsets(res_dst, n_rows),
+            row_ptr=res_ptr,
         )
 
     def push_planes(self, blk, f):

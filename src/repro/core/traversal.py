@@ -134,16 +134,18 @@ class DensityOracle:
         )
 
 
-def degree_vector(dst, n_dst: int) -> jax.Array:
+def degree_vector(row_ptr) -> jax.Array:
     """Per-destination degree of one destination-major edge block (padding
-    excluded): the differences of its row offsets.
+    excluded): the differences of its ``(n_dst + 1,)`` row offsets.
 
     The single convention behind the anticipatory oracle on BOTH drivers:
-    ``bfs`` feeds the full symmetric edge list (``n_dst == n``), the
-    distributed driver its block (``n_r`` bound, followed by a grid-row
-    psum) — one definition, so the two m_f signals cannot drift.
+    ``bfs`` feeds the offsets it searches on the device over the full
+    symmetric edge list (``n_dst == n``), the distributed driver its
+    block's offsets, built on the host with the partition and placed at
+    set-up (``n_r`` rows, followed by a grid-row psum) — one definition,
+    so the two m_f signals cannot drift.
     """
-    return jnp.diff(expand_mod.row_offsets(dst, n_dst))
+    return jnp.diff(row_ptr)
 
 
 def edge_signals(deg, new, parent):
@@ -431,7 +433,8 @@ def level_once(src, dst, n, policy: TraversalPolicy, oracle: DensityOracle,
     if expand is None:
         expand = expand_mod.resolve("coo")
     if block is None:
-        block = expand.local_block(src, dst, (), n, n)
+        block = expand.local_block(src, dst, expand_mod.row_offsets(dst, n),
+                                   (), n, n)
     x = None
     if alg is not None and alg.needs_values:
         x = alg.source_values(state.value, deg)

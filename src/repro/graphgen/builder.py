@@ -201,6 +201,17 @@ def edge_degrees(
     return np.bincount(dst[valid], minlength=n_rows)[:n_rows]
 
 
+def degree_offsets(deg: np.ndarray) -> np.ndarray:
+    """(..., n_rows + 1) int32 row offsets of destination-major edge arrays
+    from their (..., n_rows) degree histograms: each row's first edge, as a
+    binary search per row over the arrays gives it, with the sentinel tail
+    past the last row (the last offset is the valid edge count)."""
+    deg = np.asarray(deg)
+    out = np.zeros(deg.shape[:-1] + (deg.shape[-1] + 1,), np.int32)
+    np.cumsum(deg, axis=-1, out=out[..., 1:])
+    return out
+
+
 def ell_graph_arrays(
     src: np.ndarray, dst: np.ndarray, n: int, deg_multiple: int = 8
 ) -> tuple[np.ndarray, int]:
@@ -227,8 +238,10 @@ def hybrid_graph_arrays(
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
     """Whole-graph hybrid COO/ELL split for the single-device driver.
 
-    Returns (slab, residue src, residue dst, k); the residue arrays are
-    sentinel-padded ((n, n)) to a static ``res_multiple`` capacity.
+    Returns (slab, residue src, residue dst, residue row offsets, k); the
+    residue arrays are sentinel-padded ((n, n)) to a static
+    ``res_multiple`` capacity, and its row offsets come from the degree
+    histogram (rows on the slab are empty in the residue).
     """
     deg = edge_degrees(src, dst, n, n)
     k = split_k or select_split_k(deg, waste_budget, deg_multiple)
@@ -237,7 +250,7 @@ def hybrid_graph_arrays(
     pad = cap - res_s.size
     res_s = np.concatenate([res_s, np.full(pad, n, np.int32)])
     res_d = np.concatenate([res_d, np.full(pad, n, np.int32)])
-    return nbr, res_s, res_d, k
+    return nbr, res_s, res_d, degree_offsets(np.where(deg > k, deg, 0)), k
 
 
 def block_pad(g: CSRGraph, multiple: int) -> CSRGraph:

@@ -499,12 +499,13 @@ def _graph500_cell(
     fn = dbfs.build_bfs(mesh, part, bcfg)
     ax_sizes = tuple(mesh.shape[a] for a in bcfg.all_axes)
     blk = _sds(ax_sizes + (e_cap,), jnp.int32)
+    ptr = _sds(ax_sizes + (part.n_r + 1,), jnp.int32)  # the blocks' row offsets
     blk_sh = NamedSharding(mesh, P(*bcfg.row_axes, bcfg.col_axis, None))
     return Cell(
         spec.arch_id, shape.name, "bfs",
         fn=fn,
-        args=(blk, blk, _sds((), jnp.int32)),
-        in_shardings=(blk_sh, blk_sh, NamedSharding(mesh, P())),
+        args=(blk, blk, ptr, _sds((), jnp.int32)),
+        in_shardings=(blk_sh, blk_sh, blk_sh, NamedSharding(mesh, P())),
         meta=dict(
             model_flops=2.0 * m_sym,  # one compare+select per directed edge
             n_edges=m_sym,

@@ -155,15 +155,15 @@ for alg in ("sssp", "cc"):
             cfg = dbfs.DistBFSConfig(mode=mode, policy=pol, algebra=alg,
                                      max_levels=512)
             fn = dbfs.build_bfs(mesh, part, cfg, stats=stats)
-            src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-            val, lev, dep = fn(src_l, dst_l, roots)
+            blocks = dbfs.shard_blocked(mesh, bg, cfg)
+            val, lev, dep = fn(*blocks, roots)
             got = np.asarray(val)[0][:n].astype(np.int64)
             np.testing.assert_array_equal(
                 got, host[alg], err_msg=f"{alg}/{mode}/{pol}")
             if pol == "direction_opt":
                 # ledger <-> HLO reconciliation rides the same build
                 compiled = jax.jit(fn).lower(
-                    src_l, dst_l, jax.ShapeDtypeStruct((1,), jnp.int32)
+                    *blocks, jax.ShapeDtypeStruct((1,), jnp.int32)
                 ).compile()
                 cmp = roofline.compare_comm_stats(stats, compiled.as_text())
                 assert cmp.match, (alg, mode, pol, cmp.diff())
@@ -202,8 +202,8 @@ host = validate.reference_pagerank(g, n=part.n)
 cfg = dbfs.DistBFSConfig(mode="auto", policy="top_down", algebra="pagerank",
                          max_levels=256)
 fn = dbfs.build_bfs(mesh, part, cfg)
-src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-val, lev, dep = fn(src_l, dst_l, jnp.asarray(np.array([3], np.int32)))
+blocks = dbfs.shard_blocked(mesh, bg, cfg)
+val, lev, dep = fn(*blocks, jnp.asarray(np.array([3], np.int32)))
 got = np.asarray(val)[0]
 assert int(dep) < 256
 assert np.abs(got - host).max() < 1e-3, np.abs(got - host).max()

@@ -206,12 +206,12 @@ def prop(seed):
     assert bg.e_cap == 1024  # pinned -> the compiled fns are reused
     roots = rng.choice(n, size=B, replace=False).astype(np.int32)
     for (mode, pol), (fn, cfg) in fns.items():
-        src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-        pb, lb, db = fn(src_l, dst_l, jnp.asarray(roots))
+        blocks = dbfs.shard_blocked(mesh, bg, cfg)
+        pb, lb, db = fn(*blocks, jnp.asarray(roots))
         pb, lb = np.asarray(pb), np.asarray(lb)
         assert pb.shape[0] == B
         for k, r in enumerate(roots):
-            p1, l1, d1 = fn(src_l, dst_l, jnp.int32(int(r)))
+            p1, l1, d1 = fn(*blocks, jnp.int32(int(r)))
             np.testing.assert_array_equal(
                 pb[k], np.asarray(p1), err_msg=f"{mode}/{pol}/root {r}")
             np.testing.assert_array_equal(
@@ -220,11 +220,11 @@ def prop(seed):
 prop()
 # root validation rides the same wrapper in the distributed driver
 fn, cfg = fns["raw", "top_down"]
-src_l, dst_l = dbfs.shard_blocked(
+blocks = dbfs.shard_blocked(
     mesh, csrmod.partition_2d(g0, rows=ROWS, cols=COLS, e_cap_multiple=1024), cfg)
 for bad in (np.array([1, 1], np.int32), np.array([n], np.int32)):
     try:
-        fn(src_l, dst_l, bad)
+        fn(*blocks, bad)
         raise SystemExit(f"no error for roots {bad}")
     except ValueError:
         pass
@@ -273,6 +273,7 @@ B = 3
 part = csrmod.Partition2D(n=1 << 14, n_orig=1 << 14, rows=2, cols=2)
 mesh = jax.make_mesh((2, 2), ("data", "model"))
 blk = jax.ShapeDtypeStruct((2, 2, 1024), jnp.int32)
+ptr = jax.ShapeDtypeStruct((2, 2, part.n_r + 1), jnp.int32)
 for mode in ("raw", "bitmap", "auto", "btfly"):
     stage = (lambda z: z + "[btfly:0]") if mode == "btfly" else (lambda z: z)
     for pol in ("top_down", "bottom_up", "direction_opt"):
@@ -281,7 +282,7 @@ for mode in ("raw", "bitmap", "auto", "btfly"):
             mesh, part, dbfs.DistBFSConfig(mode=mode, policy=pol), stats=stats
         )
         compiled = jax.jit(fn).lower(
-            blk, blk, jax.ShapeDtypeStruct((B,), jnp.int32)
+            blk, blk, ptr, jax.ShapeDtypeStruct((B,), jnp.int32)
         ).compile()
         cmp = roofline.compare_comm_stats(stats, compiled.as_text())
         assert cmp.match, (mode, pol, cmp.diff())

@@ -92,16 +92,17 @@ def test_hybrid_expansion_compiles(one_chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def push(nbr, rs, rd, f):
-        blk = backend.local_block(rs, rd, (nbr, rs, rd), N, N)
+    def push(nbr, rs, rd, ptr, f):
+        blk = backend.local_block(rs, rd, ptr, (nbr, rs, rd, ptr), N, N)
         return backend.push_planes(blk, f)
 
-    def pull(nbr, rs, rd, f, u):
-        blk = backend.local_block(rs, rd, (nbr, rs, rd), N, N)
+    def pull(nbr, rs, rd, ptr, f, u):
+        blk = backend.local_block(rs, rd, ptr, (nbr, rs, rd, ptr), N, N)
         return backend.pull_planes(blk, f, u)
 
     args = (sds((N, k), jnp.int32), sds((r_cap,), jnp.int32),
-            sds((r_cap,), jnp.int32), sds((B, N), jnp.bool_))
+            sds((r_cap,), jnp.int32), sds((N + 1,), jnp.int32),
+            sds((B, N), jnp.bool_))
     assert "tpu_custom_call" in _compile(push, *args)
     assert "tpu_custom_call" in _compile(pull, *args, sds((B, N), jnp.bool_))
 
@@ -122,8 +123,9 @@ def test_distributed_bfs_level_compiles(topo):
                                     sharding=NamedSharding(mesh, spec))
 
     roots = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=NamedSharding(mesh, P()))
-    args = (blocks(e_cap), blocks(e_cap), blocks(part.n_r, k), blocks(e_cap),
-            blocks(e_cap), roots)
+    args = (blocks(e_cap), blocks(e_cap), blocks(part.n_r + 1),
+            blocks(part.n_r, k), blocks(e_cap), blocks(e_cap),
+            blocks(part.n_r + 1), roots)
     hlo = _compile(dbfs.build_bfs(mesh, part, cfg), *args)
     assert "tpu_custom_call" in hlo
     assert "all-gather" in hlo or "all-reduce" in hlo

@@ -51,8 +51,8 @@ mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 bg = csrmod.partition_2d(g, rows=4, cols=2)  # chunk stays a 1024-multiple
 cfg = dbfs.DistBFSConfig(row_axes=("pod", "data"), col_axis="model", mode="auto")
 fn = dbfs.build_bfs(mesh, bg, cfg)
-src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-parent, level, depth = fn(src_l, dst_l, jnp.int32(0))
+blocks = dbfs.shard_blocked(mesh, bg, cfg)
+parent, level, depth = fn(*blocks, jnp.int32(0))
 ref = validate.reference_bfs(g, 0)
 assert np.array_equal(np.asarray(level)[:g.n], ref)
 res = validate.validate_bfs_tree(g, np.asarray(parent)[:g.n], 0)
@@ -219,9 +219,9 @@ mesh = jax.make_mesh((2, 2), ("data", "model"))
 bg = csrmod.partition_2d(g, rows=2, cols=2)
 assert cc.BucketLadder.default(bg.part.chunk).specs  # sparse buckets exist
 fn = dbfs.build_bfs(mesh, bg, dbfs.DistBFSConfig(mode="auto"))
-src_l, dst_l = dbfs.shard_blocked(mesh, bg, dbfs.DistBFSConfig(mode="auto"))
+blocks = dbfs.shard_blocked(mesh, bg, dbfs.DistBFSConfig(mode="auto"))
 root = int(np.argmax(g.degrees()))
-parent, level, depth = fn(src_l, dst_l, jnp.int32(root))
+parent, level, depth = fn(*blocks, jnp.int32(root))
 assert np.array_equal(np.asarray(level)[:g.n], validate.reference_bfs(g, root))
 assert validate.validate_bfs_tree(g, np.asarray(parent)[:g.n], root).ok
 print("SCALE18 OK")

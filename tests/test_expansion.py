@@ -116,6 +116,68 @@ def test_blocked_containers_cover_every_edge():
                 assert got == want, (i, j, type(blocks).__name__)
 
 
+def _graph_with_empty_block():
+    """Edges among the first 40 vertices only: empty rows everywhere past
+    them, and on any grid the blocks away from the first row and column
+    hold no edge at all."""
+    rng = np.random.default_rng(11)
+    return builder.build_csr(rng.integers(0, 40, size=(120, 2)), n=1 << 11)
+
+
+@pytest.mark.parametrize("graph", ["kronecker", "empty_block"])
+@pytest.mark.parametrize("grid", [(1, 1), (2, 2), (2, 3)], ids=["1x1", "2x2", "2x3"])
+def test_host_row_offsets_equal_device_search(grid, graph):
+    """The row offsets built on the host at set-up (every block's, and the
+    hybrid residue's) equal the device binary search over the same
+    destination arrays, element for element, sentinel tail included."""
+    from repro.core import csr as csrmod
+
+    if graph == "kronecker":  # hub-heavy
+        g = builder.build_csr(kronecker.kronecker_edges(10, seed=6), n=1 << 10)
+    else:
+        g = _graph_with_empty_block()
+    rows, cols = grid
+    bg = csrmod.partition_2d(g, rows=rows, cols=cols, chunk_multiple=64)
+    hyb = csrmod.hybrid_blocked(bg)
+    n_r = bg.part.n_r
+    assert bg.row_ptr.shape == hyb.res_row_ptr.shape == (rows, cols, n_r + 1)
+    assert bg.row_ptr.dtype == hyb.res_row_ptr.dtype == np.int32
+    if graph == "empty_block":
+        assert (bg.degrees() == 0).any()
+        assert (bg.e_counts == 0).any() == (rows * cols > 1)
+    for i in range(rows):
+        for j in range(cols):
+            for got, dst in ((bg.row_ptr, bg.dst_local), (hyb.res_row_ptr, hyb.res_dst)):
+                want = expand.row_offsets(jnp.asarray(dst[i, j]), n_r)
+                np.testing.assert_array_equal(got[i, j], np.asarray(want),
+                                              err_msg=f"{grid} block {i},{j}")
+            assert bg.row_ptr[i, j, -1] == bg.e_counts[i, j]
+            assert hyb.res_row_ptr[i, j, -1] == (hyb.res_dst[i, j] < n_r).sum()
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_distributed_program_never_searches_row_offsets(backend, monkeypatch):
+    """The distributed program reads the offsets placed at set-up: tracing
+    it (direction_opt, so the degree vector is built too) never calls the
+    device search."""
+    import jax
+
+    from repro.core import csr as csrmod, distributed_bfs as dbfs
+
+    g = builder.build_csr(kronecker.kronecker_edges(8, seed=1), n=256)
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    bg = csrmod.partition_2d(g, rows=1, cols=1)
+    cfg = dbfs.DistBFSConfig(mode="auto", policy="direction_opt", expand=backend)
+    fn = dbfs.build_bfs(mesh, bg, cfg)
+    blocks = dbfs.shard_blocked(mesh, bg, cfg)
+
+    def searched(*args, **kwargs):
+        raise AssertionError("expand.row_offsets called in the distributed program")
+
+    monkeypatch.setattr(expand, "row_offsets", searched)
+    assert "while" in fn.lower_call(*blocks, jnp.int32(3)).as_text()
+
+
 def _python_spmv_oracle(nbr, bits, n_cols):
     out = np.full(nbr.shape[0], spmv_ref.INF, np.int64)
     for r in range(nbr.shape[0]):
@@ -152,7 +214,8 @@ def test_coo_first_hit_matches_segment_min(density):
                 hit = valid & f[p][np.minimum(src, part.n_c - 1)]
                 np.minimum.at(want[p], dst[hit], src[hit])
             want = want[:, : part.n_r]
-            blk = backend.local_block(jnp.asarray(src), jnp.asarray(dst), (),
+            blk = backend.local_block(jnp.asarray(src), jnp.asarray(dst),
+                                      jnp.asarray(bg.row_ptr[i, j]), (),
                                       part.n_r, part.n_c)
             push = backend.push_planes(blk, jnp.asarray(f))
             np.testing.assert_array_equal(np.asarray(push), want)
@@ -160,13 +223,14 @@ def test_coo_first_hit_matches_segment_min(density):
             np.testing.assert_array_equal(
                 np.asarray(pull), np.where(un, want, spmv_ref.INF)
             )
-            deg = traversal.degree_vector(jnp.asarray(dst), part.n_r)
+            deg = traversal.degree_vector(jnp.asarray(bg.row_ptr[i, j]))
             np.testing.assert_array_equal(
                 np.asarray(deg), np.bincount(dst[valid], minlength=part.n_r)
             )
     # no sentinel tail: empty rows past the last edge stay empty
-    blk = backend.local_block(jnp.asarray([2, 5], jnp.int32),
-                              jnp.asarray([0, 0], jnp.int32), (), 3, 8)
+    tail_dst = jnp.asarray([0, 0], jnp.int32)
+    blk = backend.local_block(jnp.asarray([2, 5], jnp.int32), tail_dst,
+                              expand.row_offsets(tail_dst, 3), (), 3, 8)
     push = backend.push_planes(blk, jnp.ones((1, 8), bool))
     np.testing.assert_array_equal(np.asarray(push), [[2, spmv_ref.INF, spmv_ref.INF]])
 
@@ -264,9 +328,9 @@ def test_build_bfs_rejects_unknown_backend_and_bad_arity():
     cfg = dbfs.DistBFSConfig(mode="raw", expand="hybrid")
     fn = dbfs.build_bfs(mesh, bg, cfg)
     blocks = dbfs.shard_blocked(mesh, bg, cfg)
-    assert len(blocks) == 5  # src, dst, slab, residue src/dst
+    assert len(blocks) == 7  # src, dst, row offsets, slab, residue src/dst/offsets
     with pytest.raises(TypeError, match="shard_blocked"):
-        fn(blocks[0], blocks[1], jnp.int32(0))  # COO arity with hybrid cfg
+        fn(*blocks[:3], jnp.int32(0))  # COO arity with hybrid cfg
 
 
 def _run(snippet: str, devices: int = 4, timeout: int = 1200) -> str:

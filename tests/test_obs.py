@@ -57,7 +57,7 @@ def test_setup_spans_name_kernel1_and_placement(annotations):
     bg = csr.partition_2d(g, rows=1, cols=1)
     cfg = dbfs.DistBFSConfig(expand="hybrid")
     blocks = dbfs.shard_blocked(runtime.grid_mesh(1, 1), bg, cfg)
-    assert len(blocks) == 5
+    assert len(blocks) == 7  # src, dst, row offsets, slab, residue src/dst/offsets
     assert [n for e, n in annotations if e == "enter"] == ["repro.hybrid_blocked",
                                                           "repro.device_put"]
 

@@ -163,8 +163,9 @@ def test_level_loop_ops_lie_in_one_phase(program):
 @pytest.mark.parametrize("program", CASES, indirect=True, ids="-".join)
 def test_expansion_is_slab_or_residue(program):
     """Expansion work runs under ``slab`` (ELL) or ``residue`` (COO) of a
-    push or pull pass, as the backend stores the block, and the row-offset
-    searches run outside the level loop."""
+    push or pull pass, as the backend stores the block, and what remains
+    of the row offsets (reading the placed arrays) runs outside the level
+    loop."""
     _, expand, comps, entry, loop = program
     parts = {phases.phase(i[2]) for i in loop if i[1] == "fusion"}
     parts = {p.rsplit("/", 1)[-1] for p in parts if phases.is_expand(p)}

@@ -107,10 +107,11 @@ from repro.launch import roofline
 part = csrmod.Partition2D(n=1 << 16, n_orig=1 << 16, rows=2, cols=2)
 mesh = jax.make_mesh((2, 2), ("data", "model"))
 blk = jax.ShapeDtypeStruct((2, 2, 4096), jnp.int32)
+ptr = jax.ShapeDtypeStruct((2, 2, part.n_r + 1), jnp.int32)
 for mode in ("raw", "bitmap", "auto"):
     stats = CommStats()
     fn = dbfs.build_bfs(mesh, part, dbfs.DistBFSConfig(mode=mode), stats=stats)
-    compiled = jax.jit(fn).lower(blk, blk, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    compiled = jax.jit(fn).lower(blk, blk, ptr, jax.ShapeDtypeStruct((), jnp.int32)).compile()
     cmp = roofline.compare_comm_stats(stats, compiled.as_text())
     assert cmp.match, (mode, cmp.diff())
     # every BFS exchange zone is in the ledger

@@ -215,8 +215,8 @@ for mode in ("raw", "bitmap", "auto", "btfly"):
     for pol in ("top_down", "bottom_up", "direction_opt"):
         cfg = dbfs.DistBFSConfig(mode=mode, policy=pol, alpha=0.01, beta=0.002)
         fn = dbfs.build_bfs(mesh, bg, cfg)
-        src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-        parent, level, depth = fn(src_l, dst_l, jnp.int32(0))
+        blocks = dbfs.shard_blocked(mesh, bg, cfg)
+        parent, level, depth = fn(*blocks, jnp.int32(0))
         level = np.asarray(level)[:g.n]
         assert np.array_equal(level, ref), (mode, pol)
         assert validate.validate_bfs_tree(g, np.asarray(parent)[:g.n], 0, level).ok
@@ -244,11 +244,12 @@ for rows, cols, mesh_shape in ((2, 2, (2, 2)), (2, 4, (2, 4))):
     part = csrmod.Partition2D(n=1 << 16, n_orig=1 << 16, rows=rows, cols=cols)
     mesh = jax.make_mesh(mesh_shape, ("data", "model"))
     blk = jax.ShapeDtypeStruct((rows, cols, 4096), jnp.int32)
+    ptr = jax.ShapeDtypeStruct((rows, cols, part.n_r + 1), jnp.int32)
     n_stages = cols.bit_length() - 1
     for pol in ("top_down", "bottom_up", "direction_opt"):
         stats = CommStats()
         fn = dbfs.build_bfs(mesh, part, dbfs.DistBFSConfig(mode="btfly", policy=pol), stats=stats)
-        compiled = jax.jit(fn).lower(blk, blk, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+        compiled = jax.jit(fn).lower(blk, blk, ptr, jax.ShapeDtypeStruct((), jnp.int32)).compile()
         cmp = roofline.compare_comm_stats(stats, compiled.as_text())
         assert cmp.match, (cols, pol, cmp.diff())
         stages = {f"bfs/row[btfly:{t}]" for t in range(n_stages)}
@@ -293,14 +294,14 @@ for pol in ("top_down", "bottom_up", "direction_opt"):
     cfg = dbfs.DistBFSConfig(mode="btfly", policy=pol, alpha=0.01, beta=0.002)
     stats = CommStats()
     fn = dbfs.build_bfs(mesh, bg, cfg, stats=stats)
-    src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-    lowered = jax.jit(fn).lower(src_l, dst_l, jnp.int32(0)).compile()
+    blocks = dbfs.shard_blocked(mesh, bg, cfg)
+    lowered = jax.jit(fn).lower(*blocks, jnp.int32(0)).compile()
     cmp = roofline.compare_comm_stats(stats, lowered.as_text())
     assert cmp.match, (pol, cmp.diff())
     row_zone = "bfs/row-pull" if pol == "bottom_up" else "bfs/row"
     assert f"{row_zone}[btfly:fold]" in cmp.per_phase, sorted(cmp.per_phase)
     assert f"{row_zone}[btfly:unfold]" in cmp.per_phase
-    parent, level, depth = fn(src_l, dst_l, jnp.int32(0))
+    parent, level, depth = fn(*blocks, jnp.int32(0))
     level = np.asarray(level)[:g.n]
     assert np.array_equal(level, ref), (pol, np.nonzero(level != ref)[0][:10])
     assert validate.validate_bfs_tree(g, np.asarray(parent)[:g.n], 0, level).ok
@@ -349,8 +350,8 @@ def prop(seed, root):
     assert bg.e_cap == 1024  # pinned -> compiled fns are reused
     outs = {}
     for (mode, pol), (fn, cfg) in fns.items():
-        src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-        parent, level, depth = fn(src_l, dst_l, jnp.int32(root))
+        blocks = dbfs.shard_blocked(mesh, bg, cfg)
+        parent, level, depth = fn(*blocks, jnp.int32(root))
         outs[mode, pol] = (np.asarray(parent), np.asarray(level))
     for pol in ("top_down", "bottom_up", "direction_opt"):
         np.testing.assert_array_equal(outs["btfly", pol][0], outs["raw", pol][0])
@@ -383,8 +384,8 @@ bg = csrmod.partition_2d(g, rows=2, cols=4)
 for mode in ("auto", "btfly"):
     cfg = dbfs.DistBFSConfig(mode=mode, policy="top_down")
     fn = dbfs.build_bfs(mesh, bg, cfg)
-    src_l, dst_l = dbfs.shard_blocked(mesh, bg, cfg)
-    parent, level, depth = fn(src_l, dst_l, jnp.int32(0))
+    blocks = dbfs.shard_blocked(mesh, bg, cfg)
+    parent, level, depth = fn(*blocks, jnp.int32(0))
     parent = np.asarray(parent)
     assert parent[100] == 70000, (mode, parent[100])
     assert parent[70000] == 0, (mode, parent[70000])
@@ -409,11 +410,12 @@ from repro.launch import roofline
 part = csrmod.Partition2D(n=1 << 16, n_orig=1 << 16, rows=2, cols=2)
 mesh = jax.make_mesh((2, 2), ("data", "model"))
 blk = jax.ShapeDtypeStruct((2, 2, 4096), jnp.int32)
+ptr = jax.ShapeDtypeStruct((2, 2, part.n_r + 1), jnp.int32)
 for mode in ("raw", "bitmap", "auto"):
     for pol in ("bottom_up", "direction_opt"):
         stats = CommStats()
         fn = dbfs.build_bfs(mesh, part, dbfs.DistBFSConfig(mode=mode, policy=pol), stats=stats)
-        compiled = jax.jit(fn).lower(blk, blk, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+        compiled = jax.jit(fn).lower(blk, blk, ptr, jax.ShapeDtypeStruct((), jnp.int32)).compile()
         cmp = roofline.compare_comm_stats(stats, compiled.as_text())
         assert cmp.match, (mode, pol, cmp.diff())
         want = {"bfs/column", "bfs/row-pull", "bfs/transpose", "bfs/termination", "bfs/unreached"}
